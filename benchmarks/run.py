@@ -12,13 +12,15 @@ import sys
 
 
 def main() -> None:
+    sys.path.insert(0, "src")
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--csv", action="store_true")
     ap.add_argument("--fast", action="store_true",
                     help="skip the small training-based quality benchmarks")
     args = ap.parse_args()
 
-    sys.path.insert(0, "src")
     from repro.obs.clock import now
     t0 = now()
 

@@ -24,6 +24,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import ClosedJaxpr, Jaxpr
 from jax.tree_util import keystr, tree_flatten_with_path
 
 from repro.analysis.report import Finding
@@ -45,9 +46,9 @@ def _iter_eqns(jaxpr):
 
 
 def _as_jaxprs(param):
-    if isinstance(param, jax.core.ClosedJaxpr):
+    if isinstance(param, ClosedJaxpr):
         return [param.jaxpr]
-    if isinstance(param, jax.core.Jaxpr):
+    if isinstance(param, Jaxpr):
         return [param]
     if isinstance(param, (list, tuple)):
         out = []

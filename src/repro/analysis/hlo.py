@@ -79,6 +79,14 @@ _CC_TARGET_RE = re.compile(r'custom_call_target="([^"]+)"')
 _CC_NAME_RE = re.compile(r"name=([\w\-]+)")
 
 
+def kernel_name_in(text: str) -> str | None:
+    """The registered kernel name a custom-call's text carries (its op
+    metadata), or None. The longest match wins: the text of a
+    ``paged_decode_attention`` call also contains ``decode_attention``."""
+    return max((n for n in kernel_costs.KERNEL_COSTS if n in text),
+               key=len, default=None)
+
+
 def _price_custom_call(ins, shapes):
     """(flops/bytes dict | None, unpriced-name | None) for a custom-call.
 
@@ -93,8 +101,7 @@ def _price_custom_call(ins, shapes):
     names = _CC_NAME_RE.findall(ins.rest)
     name = next((n for n in names if n in kernel_costs.KERNEL_COSTS), None)
     if name is None:
-        name = next((n for n in kernel_costs.KERNEL_COSTS
-                     if n in ins.rest), None)
+        name = kernel_name_in(ins.rest)
     if name is None:
         return None, names[0] if names else target
 

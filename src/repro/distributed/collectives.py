@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -46,7 +45,9 @@ def compressed_psum(x, axis_name: str, mesh, spec: P):
         out = deq.reshape(-1)[:flat.size].reshape(xs.shape)
         return out.astype(xs.dtype)
 
-    return shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec)(x)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec)
+    with jax.set_mesh(mesh):
+        return fn(x)
 
 
 def moe_all_to_all(tokens, axis_name: str, mesh, spec_in: P, spec_out: P):
@@ -55,5 +56,7 @@ def moe_all_to_all(tokens, axis_name: str, mesh, spec_in: P, spec_out: P):
     def body(t):
         return jax.lax.all_to_all(t, axis_name, split_axis=0, concat_axis=1,
                                   tiled=True)
-    return shard_map(body, mesh=mesh, in_specs=(spec_in,),
-                     out_specs=spec_out)(tokens)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec_in,),
+                       out_specs=spec_out)
+    with jax.set_mesh(mesh):
+        return fn(tokens)

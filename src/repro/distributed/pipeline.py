@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -39,11 +38,7 @@ def pipeline_apply(mesh: Mesh, axis: str, layer_fn, params_stacked, x,
                              + x_shard.shape[1:])
         ticks = microbatches + stages - 1
         # mark carries as stage-varying for shard_map's manual-axes tracking
-        # (pvary only exists on jax versions with the varying-axes type
-        # system; earlier shard_map needs no annotation)
-        out = jnp.zeros_like(mb)
-        if hasattr(jax.lax, "pvary"):
-            out = jax.lax.pvary(out, axis)
+        out = jax.lax.pcast(jnp.zeros_like(mb), axis, to="varying")
 
         def chunk_fn(c):
             def body(h, lp):
@@ -68,18 +63,17 @@ def pipeline_apply(mesh: Mesh, axis: str, layer_fn, params_stacked, x,
                                     for i in range(stages)])
             return (buf, out), None
 
-        buf0 = jnp.zeros_like(mb[0])
-        if hasattr(jax.lax, "pvary"):
-            buf0 = jax.lax.pvary(buf0, axis)
+        buf0 = jax.lax.pcast(jnp.zeros_like(mb[0]), axis, to="varying")
         (_, out), _ = jax.lax.scan(tick, (buf0, out), jnp.arange(ticks))
         # only the last stage holds real outputs; broadcast them
         out = jax.lax.psum(
             jnp.where(s_idx == stages - 1, out, jnp.zeros_like(out)), axis)
         return out.reshape(x_shard.shape)
 
-    fn = shard_map(stage_body, mesh=mesh,
-                   in_specs=(P(axis), P()), out_specs=P())
-    return fn(params_stacked, x)
+    fn = jax.shard_map(stage_body, mesh=mesh,
+                       in_specs=(P(axis), P()), out_specs=P())
+    with jax.set_mesh(mesh):
+        return fn(params_stacked, x)
 
 
 def bubble_fraction(stages: int, microbatches: int) -> float:

@@ -252,19 +252,32 @@ def _copy_group_page(seg_caches, segs, src, dst):
     return out
 
 
+def _copy_stacked_pages(pool, srcs, dsts):
+    """:func:`kops.copy_pages` on a scanned segment's ``(L, n_pages, ...)``
+    pool: the layers merge into the page axis (a free reshape) and every
+    pair repeats per layer at that layer's page offset, so all layers copy
+    in the one kernel call. (0, 0) padding becomes each layer's null-page
+    self-copy, still a no-op."""
+    n_l, n_p = pool.shape[:2]
+    off = (jnp.arange(n_l, dtype=jnp.int32) * n_p)[:, None]
+    flat = kops.copy_pages(pool.reshape((n_l * n_p,) + pool.shape[2:]),
+                           (srcs[None] + off).reshape(-1),
+                           (dsts[None] + off).reshape(-1))
+    return flat.reshape(pool.shape)
+
+
 def _copy_group_pages(seg_caches, segs, srcs, dsts):
     """Batched :func:`_copy_group_page`: apply a whole step's COW pair set
     (``srcs``/``dsts`` fixed-length int32 vectors, (0, 0) null-page pairs as
     padding) to every attention pool of a cache group in one dispatch."""
     out = []
     for seg_c, seg in zip(seg_caches, segs):
-        axis = 1 if seg.scan else 0
+        copy = _copy_stacked_pages if seg.scan else kops.copy_pages
 
         def cp(blk):
             if "attn" not in blk:
                 return blk
-            a = {name: (pl.at[:, dsts].set(pl[:, srcs]) if axis
-                        else kops.copy_pages(pl, srcs, dsts))
+            a = {name: copy(pl, srcs, dsts)
                  for name, pl in blk["attn"].items()}
             return dict(blk, attn=a)
 

@@ -79,7 +79,7 @@ def _flash_attention(out, ops):
 
 @register("chunk_attention")
 def _chunk_attention(out, ops):
-    # q (B,C,[Hkv,g|H],dh), k (B,Sk,Hkv,dh), v, q_positions, k_positions
+    # q (B,C,H,dh), k (B,Sk,Hkv,dh), v, q_positions, k_positions
     sk = ops[1].dims[1]
     return {"flops": 4.0 * ops[0].elems * sk,
             "bytes": _io_bytes(out, ops)}
@@ -87,7 +87,7 @@ def _chunk_attention(out, ops):
 
 @register("mla_chunk_attention")
 def _mla_chunk_attention(out, ops):
-    # q_lat (B,C,H,L), q_rope (B,C,H,R), latent (B,Sk,L), rope (B,Sk,R):
+    # q_lat (B,C*H,L), q_rope (B,C*H,R), latent (B,Sk,L), rope (B,Sk,R):
     # scores contract L+R per head, values reuse the latent (L out dims)
     sk = ops[2].dims[1]
     return {"flops": 2.0 * sk * (2 * ops[0].elems + ops[1].elems),
@@ -96,39 +96,41 @@ def _mla_chunk_attention(out, ops):
 
 @register("decode_attention")
 def _decode_attention(out, ops):
-    # q (B,[Hkv,g|H],dh), k_cache (B,S,Hkv,dh), v_cache, positions, t
-    s = ops[1].dims[1]
-    return {"flops": 4.0 * ops[0].elems * s,
+    # t (B,) [scalar prefetch], q (B,Hkv,g,dh), k_cache (B,S,Hkv,dh),
+    # v_cache, positions (B,1,S)
+    s = ops[2].dims[1]
+    return {"flops": 4.0 * ops[1].elems * s,
             "bytes": _io_bytes(out, ops)}
 
 
 @register("paged_decode_attention")
 def _paged_decode_attention(out, ops):
-    # page_map (B,n_pp) [scalar prefetch], q (B,Hkv,g,dh),
-    # k_pool (n_pages,p_sz,Hkv,dh), v_pool, pos_pool, t
+    # page_map (B,n_pp), t (B,) [scalar prefetch], q (B,Hkv,g,dh),
+    # k_pool (n_pages,p_sz,Hkv,dh), v_pool, pos_pool (n_pages,1,p_sz)
     b, n_pp = ops[0].dims
-    p_sz = ops[2].dims[1]
-    row = ops[2].bytes / max(ops[2].dims[0], 1)     # one page of k
+    p_sz = ops[3].dims[1]
+    row = ops[3].bytes / max(ops[3].dims[0], 1)     # one page of k
     # traffic: q + out + the GATHERED k/v/pos pages, never the whole pool
     gathered = b * n_pp * (2.0 * row
-                           + ops[4].bytes / max(ops[4].dims[0], 1))
-    return {"flops": 4.0 * ops[1].elems * n_pp * p_sz,
-            "bytes": float(ops[0].bytes + ops[1].bytes + out.bytes
-                           + gathered)}
+                           + ops[5].bytes / max(ops[5].dims[0], 1))
+    return {"flops": 4.0 * ops[2].elems * n_pp * p_sz,
+            "bytes": float(ops[0].bytes + ops[1].bytes + ops[2].bytes
+                           + out.bytes + gathered)}
 
 
 @register("paged_mla_decode_attention")
 def _paged_mla_decode_attention(out, ops):
-    # page_map (B,n_pp) [scalar prefetch], q_lat (B,H,L), q_rope (B,H,R),
-    # lat_pool (n_pages,p_sz,L), rope_pool (n_pages,p_sz,R), pos_pool, t
+    # page_map (B,n_pp), t (B,) [scalar prefetch], q_lat (B,H,L),
+    # q_rope (B,H,R), lat_pool (n_pages,p_sz,L), rope_pool (n_pages,p_sz,R),
+    # pos_pool (n_pages,1,p_sz)
     b, n_pp = ops[0].dims
-    p_sz = ops[3].dims[1]
+    p_sz = ops[4].dims[1]
     s = n_pp * p_sz
     gathered = b * n_pp * sum(o.bytes / max(o.dims[0], 1)
-                              for o in ops[3:6])
-    return {"flops": 2.0 * s * (2 * ops[1].elems + ops[2].elems),
-            "bytes": float(ops[0].bytes + ops[1].bytes + ops[2].bytes
-                           + out.bytes + gathered)}
+                              for o in ops[4:7])
+    return {"flops": 2.0 * s * (2 * ops[2].elems + ops[3].elems),
+            "bytes": float(sum(o.bytes for o in ops[:4]) + out.bytes
+                           + gathered)}
 
 
 # --- data movement / recurrences -------------------------------------------

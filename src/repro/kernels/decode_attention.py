@@ -1,18 +1,26 @@
 """Pallas TPU single-token decode attention against a (ring-buffer) KV cache.
 
-Grid: (B, Hkv, k_blocks) — k innermost/sequential with online-softmax scratch.
-The query block is the (G, dh) group of q heads sharing one KV head (GQA kept
-grouped here, unlike prefill: at decode the q side is tiny and the cache read
-is the bottleneck, so we never materialize broadcast KV). Masking uses the
-cache's absolute-position lane (-1 = empty slot), which makes the same kernel
-correct for linear and ring-buffer (sliding-window) caches.
+Grid: (B, k_blocks) — k innermost/sequential with online-softmax scratch.
+Each grid step takes ALL KV heads of one key block, ``(block_k, Hkv, dh)``:
+the block's last two dims are the cache's own, which the TPU's (8, 128)
+tiling accepts, and the cache keeps its HBM layout (a flattened
+``(S, Hkv*dh)`` view would cost a relayout copy of the whole cache per
+call). Inside the block each KV head is a strided sublane read. The query
+block is ``(Hkv, G, dh)``: each KV head scores its G grouped q heads in one
+(G, dh) x (dh, block_k) product (GQA
+kept grouped here, unlike prefill: at decode the q side is tiny and the
+cache read is the bottleneck, so we never materialize broadcast KV).
+Masking uses the cache's absolute-position lane (-1 = empty slot), viewed
+as ``(B, 1, S)`` so each block is a lane row; the query position rides in
+as a scalar-prefetch operand (SMEM). The same kernel is therefore correct
+for linear and ring-buffer (sliding-window) caches.
 
 ``paged_decode_attention`` is the same online-softmax walk over *paged*
 pools: the per-slot page list rides in as a scalar-prefetch operand, so the
-BlockSpec index map sends block (bi, hi, ki) straight to pool row
-``page_map[bi, ki]`` — the K/V pages stream from HBM exactly like the dense
-ring blocks, with no gathered intermediate. Null-page entries (id 0) are
-masked inside the kernel body.
+BlockSpec index map sends block (bi, ki) straight to pool row
+``page_map[bi, ki]`` — one ``(page_size, Hkv, dh)`` page of every KV head
+streams from HBM exactly like the dense ring blocks, with no gathered
+intermediate. Null-page entries (id 0) are masked inside the kernel body.
 
 ``paged_mla_decode_attention`` extends that walk to MLA-absorbed decode:
 the latent/rope pools carry no head axis (every q head reads the same
@@ -30,50 +38,58 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels import online_softmax as osm
 
 
-def _kernel(q_ref, k_ref, v_ref, pos_ref, t_ref, o_ref, m_scr, l_scr, acc_scr,
-            *, scale, n_k, window):
-    ki = pl.program_id(2)
+def _gqa_step(q_ref, k_ref, v_ref, allow, o_ref, m_scr, l_scr, acc_scr, *,
+              ki, n_k, scale):
+    """One key block of grouped-query decode: q_ref (1, Hkv, G, dh),
+    k/v_ref (1, bk, Hkv, dh), ``allow`` (1, bk) live-key mask."""
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        osm.init(m_scr, l_scr, acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)           # (G, dh)
-    k = k_ref[0, :, 0].astype(jnp.float32)        # (block_k, dh)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    pos = pos_ref[0]                              # (block_k,)
-    t = t_ref[0]
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (G, bk)
-    allow = (pos >= 0) & (pos <= t)
-    if window is not None:
-        allow = allow & (pos > t - window)
-    s = jnp.where(allow[None, :], s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=1)
-    acc_scr[...] = (corr[:, None] * acc_scr[...]
-                    + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ()))))
-    m_scr[...] = m_new
+    for h in range(q_ref.shape[1]):
+        q = q_ref[0, h].astype(jnp.float32)                   # (G, dh)
+        k = k_ref[0, :, h].astype(jnp.float32)                # (bk, dh)
+        v = v_ref[0, :, h].astype(jnp.float32)
+        s = jnp.where(allow, osm.scores(q, k, scale), osm.NEG_INF)
+        osm.update(s, v, m_scr.at[h], l_scr.at[h], acc_scr.at[h])
 
     @pl.when(ki == n_k - 1)
     def _finalize():
-        denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
-        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        o_ref[0] = osm.result(l_scr[...], acc_scr[...]).astype(o_ref.dtype)
+
+
+def _live(pos, t, window):
+    allow = (pos >= 0) & (pos <= t)
+    if window is not None:
+        allow = allow & (pos > t - window)
+    return allow
+
+
+def _kernel(t_ref, q_ref, k_ref, v_ref, pos_ref, o_ref, m_scr, l_scr,
+            acc_scr, *, scale, n_k, window):
+    bi, ki = pl.program_id(0), pl.program_id(1)
+    allow = _live(pos_ref[0], t_ref[bi], window)             # (1, bk)
+    _gqa_step(q_ref, k_ref, v_ref, allow, o_ref, m_scr, l_scr, acc_scr,
+              ki=ki, n_k=n_k, scale=scale)
+
+
+def _gqa_scratch(hkv, g, dh):
+    return [pltpu.VMEM((hkv, g, 1), jnp.float32),
+            pltpu.VMEM((hkv, g, 1), jnp.float32),
+            pltpu.VMEM((hkv, g, dh), jnp.float32)]
 
 
 def decode_attention(q, k_cache, v_cache, cache_positions, q_position, *,
                      window=None, scale=None, block_k=1024, interpret=False):
     """q: (B, H, dh); caches: (B, S, Hkv, dh); cache_positions: (B, S);
-    q_position: (B,). Returns (B, H, dh)."""
+    q_position: (B,). Returns (B, H, dh).
+
+    ``block_k`` must be a multiple of 128 unless it covers the whole cache
+    (the TPU tiles the position block's lane dim by 128)."""
     b, h, dh = q.shape
     _, s, hkv, _ = k_cache.shape
     g = h // hkv
@@ -88,68 +104,38 @@ def decode_attention(q, k_cache, v_cache, cache_positions, q_position, *,
     qp = jnp.broadcast_to(jnp.asarray(q_position, jnp.int32), (b,))
 
     kernel = functools.partial(_kernel, scale=scale, n_k=n_k, window=window)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, n_k),
+        in_specs=[
+            pl.BlockSpec((1, hkv, g, dh), lambda bi, ki, t_: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, block_k, hkv, dh),
+                         lambda bi, ki, t_: (bi, ki, 0, 0)),
+            pl.BlockSpec((1, block_k, hkv, dh),
+                         lambda bi, ki, t_: (bi, ki, 0, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda bi, ki, t_: (bi, 0, ki)),
+        ],
+        out_specs=pl.BlockSpec((1, hkv, g, dh),
+                               lambda bi, ki, t_: (bi, 0, 0, 0)),
+        scratch_shapes=_gqa_scratch(hkv, g, dh),
+    )
     out = pl.pallas_call(
         kernel,
         name="decode_attention",
-        grid=(b, hkv, n_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, dh), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, dh),
-                         lambda bi, hi, ki: (bi, ki, hi, 0)),
-            pl.BlockSpec((1, block_k, 1, dh),
-                         lambda bi, hi, ki: (bi, ki, hi, 0)),
-            pl.BlockSpec((1, block_k), lambda bi, hi, ki: (bi, ki)),
-            pl.BlockSpec((1,), lambda bi, hi, ki: (bi,)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, dh), lambda bi, hi, ki: (bi, hi, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, dh), jnp.float32),
-        ],
         interpret=interpret,
-    )(qg, kc, vc, pos, qp)
+    )(qp, qg, kc, vc, pos.reshape(b, 1, s + pk))
     return out.reshape(b, h, dh)
 
 
-def _paged_kernel(pm_ref, q_ref, k_ref, v_ref, pos_ref, t_ref, o_ref,
+def _paged_kernel(pm_ref, t_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, scale, n_k, window):
-    bi = pl.program_id(0)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0, 0].astype(jnp.float32)           # (G, dh)
-    k = k_ref[0, :, 0].astype(jnp.float32)        # (page_size, dh)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    pos = pos_ref[0]                              # (page_size,)
-    t = t_ref[0]
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+    bi, ki = pl.program_id(0), pl.program_id(1)
     # null-page entries (unallocated map slots / discarded writes) are dead
-    allow = (pos >= 0) & (pos <= t) & (pm_ref[bi, ki] > 0)
-    if window is not None:
-        allow = allow & (pos > t - window)
-    s = jnp.where(allow[None, :], s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=1)
-    acc_scr[...] = (corr[:, None] * acc_scr[...]
-                    + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ()))))
-    m_scr[...] = m_new
-
-    @pl.when(ki == n_k - 1)
-    def _finalize():
-        denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
-        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+    allow = _live(pos_ref[0], t_ref[bi], window) & (pm_ref[bi, ki] > 0)
+    _gqa_step(q_ref, k_ref, v_ref, allow, o_ref, m_scr, l_scr, acc_scr,
+              ki=ki, n_k=n_k, scale=scale)
 
 
 def paged_decode_attention(q, k_pool, v_pool, pos_pool, page_map, q_position,
@@ -157,12 +143,13 @@ def paged_decode_attention(q, k_pool, v_pool, pos_pool, page_map, q_position,
     """q: (B, H, dh); pools: (n_pages, page_size, Hkv, dh); page_map:
     (B, n_pp) int32 (0 = null page); q_position: (B,). Returns (B, H, dh).
 
-    One grid step per (slot, kv-head, page): the page id is scalar-prefetched
-    and used directly in the K/V/pos index maps, so each step DMAs exactly
-    one page — the paged analogue of the ring kernel's k-blocks.
+    One grid step per (slot, page): the page id is scalar-prefetched and
+    used directly in the K/V/pos index maps, so each step DMAs exactly one
+    page of every KV head — the paged analogue of the ring kernel's
+    k-blocks.
     """
     b, h, dh = q.shape
-    _, p_sz, hkv, _ = k_pool.shape
+    n_pages, p_sz, hkv, _ = k_pool.shape
     n_pp = page_map.shape[1]
     g = h // hkv
     scale = dh ** -0.5 if scale is None else scale
@@ -173,24 +160,21 @@ def paged_decode_attention(q, k_pool, v_pool, pos_pool, page_map, q_position,
     kernel = functools.partial(_paged_kernel, scale=scale, n_k=n_pp,
                                window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, hkv, n_pp),
+        num_scalar_prefetch=2,
+        grid=(b, n_pp),
         in_specs=[
-            pl.BlockSpec((1, 1, g, dh), lambda bi, hi, ki, pm_: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, p_sz, 1, dh),
-                         lambda bi, hi, ki, pm_: (pm_[bi, ki], 0, hi, 0)),
-            pl.BlockSpec((1, p_sz, 1, dh),
-                         lambda bi, hi, ki, pm_: (pm_[bi, ki], 0, hi, 0)),
-            pl.BlockSpec((1, p_sz), lambda bi, hi, ki, pm_: (pm_[bi, ki], 0)),
-            pl.BlockSpec((1,), lambda bi, hi, ki, pm_: (bi,)),
+            pl.BlockSpec((1, hkv, g, dh),
+                         lambda bi, ki, pm_, t_: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, p_sz, hkv, dh),
+                         lambda bi, ki, pm_, t_: (pm_[bi, ki], 0, 0, 0)),
+            pl.BlockSpec((1, p_sz, hkv, dh),
+                         lambda bi, ki, pm_, t_: (pm_[bi, ki], 0, 0, 0)),
+            pl.BlockSpec((1, 1, p_sz),
+                         lambda bi, ki, pm_, t_: (pm_[bi, ki], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, dh),
-                               lambda bi, hi, ki, pm_: (bi, hi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, dh), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, hkv, g, dh),
+                               lambda bi, ki, pm_, t_: (bi, 0, 0, 0)),
+        scratch_shapes=_gqa_scratch(hkv, g, dh),
     )
     out = pl.pallas_call(
         kernel,
@@ -198,48 +182,31 @@ def paged_decode_attention(q, k_pool, v_pool, pos_pool, page_map, q_position,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), q.dtype),
         interpret=interpret,
-    )(pm, qg, k_pool, v_pool, pos_pool, qp)
+    )(pm, qp, qg, k_pool, v_pool, pos_pool.reshape(n_pages, 1, p_sz))
     return out.reshape(b, h, dh)
 
 
-def _paged_mla_kernel(pm_ref, ql_ref, qr_ref, lat_ref, rope_ref, pos_ref,
-                      t_ref, o_ref, m_scr, l_scr, acc_scr, *, scale, n_k):
-    bi = pl.program_id(0)
-    ki = pl.program_id(1)
+def _paged_mla_kernel(pm_ref, t_ref, ql_ref, qr_ref, lat_ref, rope_ref,
+                      pos_ref, o_ref, m_scr, l_scr, acc_scr, *, scale, n_k):
+    bi, ki = pl.program_id(0), pl.program_id(1)
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        osm.init(m_scr, l_scr, acc_scr)
 
     ql = ql_ref[0].astype(jnp.float32)            # (H, L)
     qr = qr_ref[0].astype(jnp.float32)            # (H, R)
     lat = lat_ref[0].astype(jnp.float32)          # (page_size, L)
     rp = rope_ref[0].astype(jnp.float32)          # (page_size, R)
-    pos = pos_ref[0]                              # (page_size,)
-    t = t_ref[0]
-
-    s = (jax.lax.dot_general(ql, lat, (((1,), (1,)), ((), ())))
-         + jax.lax.dot_general(qr, rp, (((1,), (1,)), ((), ())))) * scale
+    s = osm.scores(ql, lat, scale) + osm.scores(qr, rp, scale)
     # null-page entries are dead even though the null page itself absorbs
     # discarded writes (its pos lane can hold live-looking values)
-    allow = (pos >= 0) & (pos <= t) & (pm_ref[bi, ki] > 0)
-    s = jnp.where(allow[None, :], s, NEG_INF)     # (H, page_size)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=1)
-    acc_scr[...] = (corr[:, None] * acc_scr[...]
-                    + jax.lax.dot_general(p, lat, (((1,), (0,)), ((), ()))))
-    m_scr[...] = m_new
+    allow = _live(pos_ref[0], t_ref[bi], None) & (pm_ref[bi, ki] > 0)
+    osm.update(jnp.where(allow, s, osm.NEG_INF), lat, m_scr, l_scr, acc_scr)
 
     @pl.when(ki == n_k - 1)
     def _finalize():
-        denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
-        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        o_ref[0] = osm.result(l_scr[...], acc_scr[...]).astype(o_ref.dtype)
 
 
 def paged_mla_decode_attention(q_lat, q_rope, lat_pool, rope_pool, pos_pool,
@@ -256,7 +223,7 @@ def paged_mla_decode_attention(q_lat, q_rope, lat_pool, rope_pool, pos_pool,
     page — no dense (B, S_logical, L) view is ever materialized.
     """
     b, h, lat_d = q_lat.shape
-    p_sz = lat_pool.shape[1]
+    n_pages, p_sz = pos_pool.shape
     n_pp = page_map.shape[1]
     r = q_rope.shape[-1]
     out_dtype = q_lat.dtype if out_dtype is None else out_dtype
@@ -265,22 +232,23 @@ def paged_mla_decode_attention(q_lat, q_rope, lat_pool, rope_pool, pos_pool,
 
     kernel = functools.partial(_paged_mla_kernel, scale=scale, n_k=n_pp)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, n_pp),
         in_specs=[
-            pl.BlockSpec((1, h, lat_d), lambda bi, ki, pm_: (bi, 0, 0)),
-            pl.BlockSpec((1, h, r), lambda bi, ki, pm_: (bi, 0, 0)),
+            pl.BlockSpec((1, h, lat_d), lambda bi, ki, pm_, t_: (bi, 0, 0)),
+            pl.BlockSpec((1, h, r), lambda bi, ki, pm_, t_: (bi, 0, 0)),
             pl.BlockSpec((1, p_sz, lat_d),
-                         lambda bi, ki, pm_: (pm_[bi, ki], 0, 0)),
+                         lambda bi, ki, pm_, t_: (pm_[bi, ki], 0, 0)),
             pl.BlockSpec((1, p_sz, r),
-                         lambda bi, ki, pm_: (pm_[bi, ki], 0, 0)),
-            pl.BlockSpec((1, p_sz), lambda bi, ki, pm_: (pm_[bi, ki], 0)),
-            pl.BlockSpec((1,), lambda bi, ki, pm_: (bi,)),
+                         lambda bi, ki, pm_, t_: (pm_[bi, ki], 0, 0)),
+            pl.BlockSpec((1, 1, p_sz),
+                         lambda bi, ki, pm_, t_: (pm_[bi, ki], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, h, lat_d), lambda bi, ki, pm_: (bi, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, lat_d),
+                               lambda bi, ki, pm_, t_: (bi, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((h,), jnp.float32),
-            pltpu.VMEM((h,), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, lat_d), jnp.float32),
         ],
     )
@@ -290,4 +258,5 @@ def paged_mla_decode_attention(q_lat, q_rope, lat_pool, rope_pool, pos_pool,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, lat_d), out_dtype),
         interpret=interpret,
-    )(pm, q_lat, q_rope, lat_pool, rope_pool, pos_pool, qp)
+    )(pm, qp, q_lat, q_rope, lat_pool, rope_pool,
+      pos_pool.reshape(n_pages, 1, p_sz))

@@ -72,7 +72,7 @@ def chunk_attention(q, k, v, q_positions, k_positions, *, window=None,
 
 def mla_chunk_attention(q_lat, q_rope, latent, rope, q_positions,
                         k_positions, *, scale, out_dtype=None,
-                        block_q=128, block_k=256):
+                        block_q=None, block_k=256):
     """Absorbed-matmul MLA chunk attention: q already carries W_UK, so the
     scores run directly over the latent cache (+ the rope side) and the
     value product reads the latent pool — no per-head K/V ever materializes.

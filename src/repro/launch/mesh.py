@@ -26,7 +26,10 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"mesh {shape} needs {need} devices, found {len(devices)} — "
             "run under launch/dryrun.py which forces host platform devices")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    # Auto axes: the partitioner places what the logical-axis constraints
+    # leave open (make_mesh defaults to Explicit axes)
+    return jax.make_mesh(shape, axes, devices=devices[:need],
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def data_axes_of(mesh) -> tuple:

@@ -1,7 +1,9 @@
 """Serving driver: slot-based continuous batching through ``repro.engine``.
 
-On the CPU container use ``--smoke``; the full-size serving cells are
-validated through the AOT dry-run. Requests are prefilled individually (with
+``--smoke`` serves the reduced same-family config, for the CPU; without
+it the model runs at its published width, which is for the chip
+(``chip_smoke.py`` at the repo root serves qwen3-1.7b that way on one
+TPU). Requests are prefilled individually (with
 staggered prompt lengths, so slots sit at *different* SOI phases) and
 inserted into engine slots; one jitted generate step then advances every
 slot per iteration — the paper's scattered-recompute pattern is resolved
@@ -51,24 +53,47 @@ deferred drain — no extra host sync), every request's lifecycle is traced
 exit the Perfetto-openable Chrome trace and/or the flat metrics JSON
 (registry snapshot + TTFT/TPOT percentiles) are written. Interval timing
 uses the shared monotonic clock ``repro.obs.now`` throughout.
+
+``main`` returns a :class:`Served` record: the generated rows plus the
+engine, config and parameters that produced them, so a caller (the chip
+smoke test) can inspect the compiled programs and re-run a request.
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import importlib
 
 import jax
 import numpy as np
 
 import repro.configs as configs
+from repro.configs.base import ModelCfg
 from repro.distributed.sharding import split_axes
 from repro.engine import SOIEngine
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 from repro.obs import (EngineTelemetry, MetricsRegistry, Tracer, now,
                        write_metrics, write_trace)
 
 
-def main(argv=None):
+@dataclasses.dataclass
+class Served:
+    """One serving run. ``tokens`` maps every admitted slot to its
+    generated ids (prefill's first token included); ``seqs`` stacks the
+    admitted rows, cut to ``--gen-len``."""
+    args: argparse.Namespace
+    cfg: ModelCfg
+    engine: SOIEngine
+    params: dict
+    prompt: jax.Array          # (batch, prompt_len) token ids
+    plens: list                # true prompt length per slot
+    tokens: dict
+    seqs: np.ndarray
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b", choices=configs.ARCHS)
     ap.add_argument("--smoke", action="store_true")
@@ -125,19 +150,38 @@ def main(argv=None):
                     help="write the flat metrics JSON (registry snapshot + "
                          "TTFT/TPOT percentiles); implies engine telemetry")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def model_config(args) -> ModelCfg:
+    mod = importlib.import_module(
+        "repro.configs." + args.arch.replace("-", "_").replace(".", "_"))
+    return (mod.smoke_config(soi=args.soi) if args.smoke
+            else mod.config(soi=args.soi))
+
+
+def make_engine(cfg: ModelCfg, args) -> SOIEngine:
+    """The engine ``main`` serves through, built from parsed arguments."""
     if args.bucket == "pow2":
         buckets = "pow2"
     elif args.bucket == "none":
         buckets = None
     else:
         buckets = tuple(int(x) for x in args.bucket.split(","))
+    return SOIEngine(cfg, max_concurrent_decodes=args.batch,
+                     max_len=args.prompt_len + args.gen_len,
+                     paged=args.paged, page_size=args.page_size,
+                     prefill_buckets=buckets,
+                     prefill_chunk=args.chunk_size,
+                     prefix_cache=args.prefix_cache,
+                     speculate=args.speculate,
+                     telemetry=bool(args.trace_out or args.metrics_out))
 
-    import importlib
-    mod = importlib.import_module(
-        "repro.configs." + args.arch.replace("-", "_").replace(".", "_"))
-    cfg = (mod.smoke_config(soi=args.soi) if args.smoke
-           else mod.config(soi=args.soi))
+
+def main(argv=None) -> Served:
+    use_compile_cache()
+    args = parse_args(argv)
+    cfg = model_config(args)
 
     rng = jax.random.PRNGKey(args.seed)
     params, _ = split_axes(T.init(rng, cfg))
@@ -147,17 +191,10 @@ def main(argv=None):
     if args.shared_prefix:
         n = min(args.shared_prefix, args.prompt_len)
         prompt = prompt.at[:, :n].set(prompt[0, :n])
-    max_len = args.prompt_len + args.gen_len
     plens = [max(1, args.prompt_len - i * args.stagger) for i in range(b)]
 
     obs_on = bool(args.trace_out or args.metrics_out)
-    engine = SOIEngine(cfg, max_concurrent_decodes=b, max_len=max_len,
-                       paged=args.paged, page_size=args.page_size,
-                       prefill_buckets=buckets,
-                       prefill_chunk=args.chunk_size,
-                       prefix_cache=args.prefix_cache,
-                       speculate=args.speculate,
-                       telemetry=obs_on)
+    engine = make_engine(cfg, args)
     state = engine.init_decode_state(params)
     registry = MetricsRegistry()
     telemetry = EngineTelemetry(
@@ -220,7 +257,8 @@ def main(argv=None):
         print(f"arch={cfg.name}: no request admitted — the paged pools "
               f"cannot back a single prompt; grow n_pages or shrink "
               f"--prompt-len")
-        return np.zeros((0, args.gen_len), np.int64)
+        return Served(args, cfg, engine, params, prompt, plens, out,
+                      np.zeros((0, args.gen_len), np.int64))
 
     n_steps = args.gen_len - 1   # every slot gains >= one token per call
 
@@ -325,7 +363,7 @@ def main(argv=None):
                           tracer=tracer)
             print(f"metrics written to {args.metrics_out}")
     print("sample:", seqs[0, :16].tolist())
-    return seqs
+    return Served(args, cfg, engine, params, prompt, plens, out, seqs)
 
 
 if __name__ == "__main__":

@@ -41,9 +41,14 @@ def make_train_step(cfg: ModelCfg, rules: ShardingRules = None, mesh=None, *,
             (l, metrics), grads = jax.value_and_grad(loss, has_aux=True)(
                 params, batch)
         else:
+            # split rows as (B/M, M) and scan over the trailing M: the
+            # data-sharded batch axis stays on the per-microbatch rows, so
+            # the scanned axis is unsharded (row i of microbatch k is row
+            # i*M + k of the batch)
             mb = jax.tree.map(
-                lambda x: x.reshape((microbatches, x.shape[0] // microbatches)
-                                    + x.shape[1:]), batch)
+                lambda x: jnp.swapaxes(
+                    x.reshape((x.shape[0] // microbatches, microbatches)
+                              + x.shape[1:]), 0, 1), batch)
 
             def body(acc, mbatch):
                 g_acc, l_acc = acc

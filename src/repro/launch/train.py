@@ -2,8 +2,8 @@
 
 Runs any ``--arch`` (full or smoke config) under the fault-tolerance
 supervisor: host-sharded data, jitted train step, async atomic checkpoints,
-restore-on-restart. On the CPU container use ``--smoke`` (reduced config) —
-the full configs are exercised via the AOT dry-run.
+restore-on-restart. ``--smoke`` (the reduced same-family config) is for the
+CPU; the full configs, at their published widths, are for the chip.
 
 Example (quickstart equivalent):
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-1.7b --smoke \
@@ -23,12 +23,14 @@ import repro.configs as configs
 from repro.data.pipeline import ShardedLMPipeline
 from repro.distributed.fault_tolerance import SupervisorConfig, TrainSupervisor
 from repro.distributed.sharding import split_axes
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models import transformer as T
 from repro.optim import adamw_init
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b", choices=configs.ARCHS)
     ap.add_argument("--smoke", action="store_true",

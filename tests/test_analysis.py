@@ -45,13 +45,14 @@ def test_undonated_big_buffer_flagged():
 
 
 def test_dropped_donation_flagged():
-    """Donating a buffer no output can alias (f32 in, bf16-only out) is
+    """Donating a buffer no output can alias (no output of its element
+    count: jax also donates to a same-size output of another dtype) is
     dropped by XLA: DON002 from the lowering trap, DroppedDonationError
     from the executing wrapper."""
     x = jnp.zeros((128, 128), jnp.float32)
 
     def drop(v):
-        return (v * 2).astype(jnp.bfloat16)
+        return (v * 2).astype(jnp.bfloat16)[0]
 
     # jax emits the dropped-donation warning once per lowering, so give the
     # static check and the executing wrapper each a fresh program

@@ -307,6 +307,19 @@ def test_kernel_custom_call_priced():
     assert got["unpriced_custom_calls"] == []
 
 
+@pytest.mark.parametrize("name", ["decode_attention", "paged_decode_attention",
+                                  "chunk_attention", "mla_chunk_attention"])
+def test_kernel_name_longest_match(name):
+    """Compiled TPU HLO carries the kernel name only inside ``op_name``; a
+    name holding another registered one (paged_decode_attention holds
+    decode_attention) resolves to the longer, so it is priced by its own
+    formula."""
+    from repro.analysis.hlo import kernel_name_in
+    assert kernel_name_in(
+        f'metadata={{op_name="jit(step)/{name}/pallas_call"}}') == name
+    assert kernel_name_in('op_name="jit(step)/mystery/pallas_call"') is None
+
+
 def test_kernel_custom_call_unpriced_reported():
     """A Pallas-target custom-call with an unknown name lands in
     unpriced_custom_calls; non-kernel targets (Sharding etc.) stay exempt."""

@@ -71,8 +71,10 @@ def test_sharded_train_step_runs_on_mesh():
         from repro.models import transformer as T
         from repro.optim import adamw_init
 
+        from jax.sharding import AxisType
         mesh = jax.make_mesh((2, 4), ("data", "model"),
-                             devices=jax.devices()[:8])
+                             devices=jax.devices()[:8],
+                             axis_types=(AxisType.Auto,) * 2)
         rules = ShardingRules(data_axes=("data",))
         cfg = C.get_smoke("qwen3-1.7b")
         pshapes, psh = S.param_shardings(cfg, rules, mesh)
