@@ -16,6 +16,7 @@ from typing import Any, Optional, Tuple
 import jax
 
 from repro.engine.contracts import host_get
+from repro.obs.spans import span
 
 Params = Any
 DecodeState = Any
@@ -62,9 +63,11 @@ class ResultTokens:
         transfer (``repro.engine.contracts.host_get``) — the sanctioned
         per-step device->host copy of the serving loop. Call it on the
         *previous* step's results after dispatching the next step, so the
-        copy overlaps device compute instead of stalling dispatch."""
-        data, logits, metrics = host_get((self.data, self.logits,
-                                          self.metrics))
+        copy overlaps device compute instead of stalling dispatch. The
+        copy is the ``engine.drain`` span (``repro.obs.span``)."""
+        with span("engine.drain"):
+            data, logits, metrics = host_get((self.data, self.logits,
+                                              self.metrics))
         return dataclasses.replace(self, data=data, logits=logits,
                                    metrics=metrics)
 

@@ -35,6 +35,12 @@ Paged engines make host-side allocation decisions between jitted steps, so
 one engine instance drives ONE live decode state and must see every
 lifecycle transition (``insert`` / ``generate`` / ``free_slot``) of it; the
 page maps enter the compiled step as data, never as trace-time constants.
+
+The host work is instrumented with ``repro.obs.span``: ``engine.generate``
+(and its parts), ``engine.prefill``, ``engine.insert``, ``engine.free_slot``
+and one ``engine.dispatch`` around every jitted call. ``compiles`` counts
+the traces of each jitted program. Both are listed in
+``docs/OBSERVABILITY.md``.
 """
 
 from __future__ import annotations
@@ -57,6 +63,11 @@ from repro.models import attention as attn
 from repro.models import decode as D
 from repro.models.attention import PagedKV
 from repro.models.transformer import _dtype, _noc, soi_partition
+from repro.obs.spans import span
+
+# the engine's jitted programs, as ``SOIEngine.compiles`` names them
+PROGRAMS = ("gen", "specgen", "ins", "prefill", "prefill_chunk", "release",
+            "scrub", "hydrate", "cow_batch", "fresh_prefix")
 
 
 def _insert_seg_rows(dst, src, slot, *, axis: int):
@@ -364,9 +375,12 @@ class SOIEngine(Engine):
         self._spec_pending = [[] for _ in range(self._slots)]
         self.spec_stats = {"windows": 0, "slot_windows": 0, "committed": 0,
                            "draft_candidates": 0, "draft_accepted": 0}
-        # traces of the jitted speculative window (the compile-count guard
-        # checks it stays at 1 regardless of K and acceptance patterns)
-        self.spec_compiles = 0
+        # traces of each jitted program (PROGRAMS), counted in its body,
+        # which runs once per trace: the serving-visible recompile counter
+        self.compiles = dict.fromkeys(PROGRAMS, 0)
+        # generate calls (and speculative windows) so far: the step number
+        # of the engine.generate span
+        self._steps = 0
         if cfg.learned_pos_len and max_len > cfg.learned_pos_len:
             # jnp.take clamps out-of-bounds rows, so decodes past the table
             # would silently reuse the LAST position embedding forever —
@@ -396,12 +410,6 @@ class SOIEngine(Engine):
             if self._chunk > max_len:
                 raise ValueError(f"prefill_chunk {self._chunk} exceeds "
                                  f"max_len {max_len}")
-        # traces of the jitted prefill programs (one per bucket, or exactly
-        # one chunk program): the serving-visible recompile counter
-        self.prefill_compiles = 0
-        # traces of the prefix-cache hydration program (compiles once on the
-        # first hit; the compile-count guard watches both counters)
-        self.hydrate_compiles = 0
         if self._paged:
             outer_len, mid_len = D.paged_group_lens(cfg, max_len)
             if not outer_len and not mid_len:
@@ -460,6 +468,7 @@ class SOIEngine(Engine):
                                 self._metrics_stride)
 
         def _gen(params, ds):
+            self.compiles["gen"] += 1
             met = _metrics(ds)
             logits, ms = generate_step(params, cfg, ds["model"], ds["tokens"],
                                        active=ds["active"],
@@ -471,7 +480,7 @@ class SOIEngine(Engine):
                     data, logits, met)
 
         def _specgen(params, ds, spec_mask):
-            self.spec_compiles += 1     # body runs once per trace
+            self.compiles["specgen"] += 1
             met = _metrics(ds)          # one sample per window (entry phase)
             ms, committed, n_acc, nxt, logits = speculative_window(
                 params, cfg, ds["model"], ds["tokens"],
@@ -485,6 +494,7 @@ class SOIEngine(Engine):
                     data, logits, met)
 
         def _ins(ds, pstate, first_token, slot, page_rows):
+            self.compiles["ins"] += 1
             model = insert_state(cfg, ds["model"], pstate, slot,
                                  page_rows=page_rows)
             return {"model": model,
@@ -492,18 +502,19 @@ class SOIEngine(Engine):
                     "active": ds["active"].at[slot].set(True)}
 
         def _prefill(params, tokens, true_length, encoder_frames):
-            self.prefill_compiles += 1      # body runs once per trace
+            self.compiles["prefill"] += 1   # one per bucket
             return D.prefill(params, cfg, tokens,
                              encoder_frames=encoder_frames,
                              max_len=max_len, true_length=true_length,
                              constrain=constrain)
 
         def _prefill_chunk(params, ms, tokens, offset, true_length):
-            self.prefill_compiles += 1      # traces ONCE for all chunks
+            self.compiles["prefill_chunk"] += 1   # ONCE for all chunks
             return D.prefill_chunk(params, cfg, ms, tokens, offset,
                                    true_length, constrain=constrain)
 
         def _fresh_prefix_state(params):
+            self.compiles["fresh_prefix"] += 1
             return D.init_decode_state(params, cfg, 1, max_len=max_len)
 
         def _scrub_model(m: dict, rows: dict) -> dict:
@@ -522,6 +533,7 @@ class SOIEngine(Engine):
             return m
 
         def _release(ds, slot, rows):
+            self.compiles["release"] += 1
             # ``rows`` indexes what gets scrubbed: released page rows in the
             # pools (paged) or the slot's own batch row (dense) — same
             # ``pos = -1`` hygiene either way, so a freed request's tokens
@@ -531,12 +543,13 @@ class SOIEngine(Engine):
                     "active": ds["active"].at[slot].set(False)}
 
         def _scrub_pages(ds, rows):
+            self.compiles["scrub"] += 1
             # eviction path: scrub freed pages without touching any slot's
             # active bit (no slot is being released)
             return dict(ds, model=_scrub_model(ds["model"], rows))
 
         def _hydrate(ms, model, rows, n_tok, n_frames):
-            self.hydrate_compiles += 1      # body runs once per trace
+            self.compiles["hydrate"] += 1
             out = dict(ms)
             if cfg.soi is None:
                 out["segments"] = _hydrate_groups(
@@ -561,6 +574,7 @@ class SOIEngine(Engine):
             # the compressed-middle pools. Vectors are fixed-length and
             # (0, 0)-padded (null-page self-copies are no-ops), so one
             # compiled program serves every COW count.
+            self.compiles["cow_batch"] += 1
             m = dict(ds["model"])
             if cfg.soi is None:
                 m["segments"] = _copy_group_pages(m["segments"],
@@ -625,6 +639,47 @@ class SOIEngine(Engine):
         return tuple(buckets)
 
     @property
+    def prefill_compiles(self) -> int:
+        """Traces of the prefill programs: one per bucket, or exactly one
+        chunk program."""
+        return self.compiles["prefill"] + self.compiles["prefill_chunk"]
+
+    @property
+    def hydrate_compiles(self) -> int:
+        """Traces of the prefix-cache hydration program (once, on the
+        first hit)."""
+        return self.compiles["hydrate"]
+
+    @property
+    def spec_compiles(self) -> int:
+        """Traces of the speculative window (1 whatever K and the
+        acceptance pattern)."""
+        return self.compiles["specgen"]
+
+    def _dispatch(self, program: str, fn, *args):
+        """Call the jitted ``fn`` (``program`` of :data:`PROGRAMS`) under
+        an ``engine.dispatch`` span, marked ``traced=1`` when the call
+        traced the program anew (a compile, or a compile-cache load)."""
+        before = self.compiles[program]
+        with span("engine.dispatch", program=program) as sp:
+            out = fn(*args)
+            if self.compiles[program] != before:
+                sp.set(traced=1)
+        return out
+
+    def _step_span(self) -> span:
+        """The ``engine.generate`` span of the step about to run: its
+        number, whether the compressed middle fires (``step_metrics``'s
+        rule on the host's clocks: some occupied slot at phase 0; read
+        before the clocks advance, from no device value) and the
+        occupied slots."""
+        occ = self._occupied
+        mid = bool((self._clock[occ] % self._metrics_stride == 0).any())
+        self._steps += 1
+        return span("engine.generate", step=self._steps - 1, mid=int(mid),
+                    active=int(occ.sum()))
+
+    @property
     def prefill_buckets(self):
         """Active bucket lengths (None = exact-length prefill)."""
         return self._buckets
@@ -684,14 +739,17 @@ class SOIEngine(Engine):
         previous step handed them straight back) — a steady-state token
         costs zero host->device transfers here, which measured as ~0.5ms
         of the paged-vs-dense per-step gap on the CPU container."""
-        pages = dict(model["pages"])
-        stale = False
-        for name, pt in (("outer", self._pt_outer), ("mid", self._pt_mid)):
-            if pt is not None and self._pm_version[name] != pt.version:
-                pages[name] = jnp.asarray(pt.map)
-                self._pm_version[name] = pt.version
-                stale = True
-        return dict(model, pages=pages) if stale else model
+        with span("engine.refresh_page_maps") as sp:
+            pages = dict(model["pages"])
+            uploaded = 0
+            for name, pt in (("outer", self._pt_outer),
+                             ("mid", self._pt_mid)):
+                if pt is not None and self._pm_version[name] != pt.version:
+                    pages[name] = jnp.asarray(pt.map)
+                    self._pm_version[name] = pt.version
+                    uploaded += 1
+            sp.set(maps=uploaded)
+        return dict(model, pages=pages) if uploaded else model
 
     def _flush_cow(self, decode_state):
         """Dispatch every pending COW copy as one compiled call. Pair
@@ -713,9 +771,10 @@ class SOIEngine(Engine):
                 o_src[:len(o)], o_dst[:len(o)] = zip(*o)
             if m:
                 m_src[:len(m)], m_dst[:len(m)] = zip(*m)
-            decode_state = self._cow_batch_fn(
-                decode_state, jnp.asarray(o_src), jnp.asarray(o_dst),
-                jnp.asarray(m_src), jnp.asarray(m_dst))
+            decode_state = self._dispatch(
+                "cow_batch", self._cow_batch_fn, decode_state,
+                jnp.asarray(o_src), jnp.asarray(o_dst), jnp.asarray(m_src),
+                jnp.asarray(m_dst))
         self._live = decode_state
         return decode_state
 
@@ -782,25 +841,29 @@ class SOIEngine(Engine):
     def _evict_entry(self, decode_state):
         """Drop the LRU prefix-index entry; scrub any page this was the
         last reference to."""
-        # pending COW copies must land first: eviction can free (and
-        # scrub) the last reference to a pending pair's SOURCE page, and a
-        # flush after that would copy scrubbed garbage into the new page
-        decode_state = self._flush_cow(decode_state)
-        e = self._prefix_index.pop_lru()
-        if e is None:
-            return decode_state
-        self._pc_stats["evictions"] += 1
-        freed_o = [pid for pid in e.outer_pages
-                   if self._pt_outer.unpin(pid)]
-        freed_m = []
-        if self._pt_mid is not None:
-            freed_m = [pid for pid in e.mid_pages if self._pt_mid.unpin(pid)]
-        if not freed_o and not freed_m:
-            return decode_state
-        rows = {"outer": self._pad_row(self._pt_outer, freed_o)}
-        if self._pt_mid is not None:
-            rows["mid"] = self._pad_row(self._pt_mid, freed_m)
-        decode_state = self._scrub_fn(decode_state, rows)
+        with span("engine.evict"):
+            # pending COW copies must land first: eviction can free (and
+            # scrub) the last reference to a pending pair's SOURCE page,
+            # and a flush after that would copy scrubbed garbage into the
+            # new page
+            decode_state = self._flush_cow(decode_state)
+            e = self._prefix_index.pop_lru()
+            if e is None:
+                return decode_state
+            self._pc_stats["evictions"] += 1
+            freed_o = [pid for pid in e.outer_pages
+                       if self._pt_outer.unpin(pid)]
+            freed_m = []
+            if self._pt_mid is not None:
+                freed_m = [pid for pid in e.mid_pages
+                           if self._pt_mid.unpin(pid)]
+            if not freed_o and not freed_m:
+                return decode_state
+            rows = {"outer": self._pad_row(self._pt_outer, freed_o)}
+            if self._pt_mid is not None:
+                rows["mid"] = self._pad_row(self._pt_mid, freed_m)
+            decode_state = self._dispatch("scrub", self._scrub_fn,
+                                          decode_state, rows)
         self._live = decode_state
         return decode_state
 
@@ -995,31 +1058,31 @@ class SOIEngine(Engine):
         if not 0 < tl <= tokens.shape[1]:
             raise ValueError(f"true_length {tl} outside (0, "
                              f"{tokens.shape[1]}]")
-        if self._chunk is not None:
-            if encoder_frames is not None:
-                raise ValueError("chunked prefill supports decoder-only "
-                                 "stacks (no encoder_frames)")
-            return self._prefill_chunked(params, tokens, tl)
-        if self._buckets is not None:
-            bucket = next(b for b in self._buckets if b >= tl)
-            pad = bucket - int(tokens.shape[1])
-            if pad > 0:
-                tokens = jnp.pad(tokens, ((0, 0), (0, pad)))
-            elif pad < 0:
-                tokens = tokens[:, :bucket]
-            logits, ms = self._prefill_fn(params, tokens,
-                                          jnp.asarray(tl, jnp.int32),
-                                          encoder_frames)
-        else:
-            if tl != tokens.shape[1]:
-                tokens = tokens[:, :tl]   # exact-length path: drop the pad
-            logits, ms = self._prefill_fn(params, tokens, None,
-                                          encoder_frames)
-        first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if self._chunk is not None and encoder_frames is not None:
+            raise ValueError("chunked prefill supports decoder-only "
+                             "stacks (no encoder_frames)")
+        with span("engine.prefill", tokens=tl) as sp:
+            if self._chunk is not None:
+                return self._prefill_chunked(params, tokens, tl, sp)
+            if self._buckets is not None:
+                bucket = next(b for b in self._buckets if b >= tl)
+                pad = bucket - int(tokens.shape[1])
+                if pad > 0:
+                    tokens = jnp.pad(tokens, ((0, 0), (0, pad)))
+                elif pad < 0:
+                    tokens = tokens[:, :bucket]
+                true_len = jnp.asarray(tl, jnp.int32)
+            else:
+                if tl != tokens.shape[1]:
+                    tokens = tokens[:, :tl]   # exact length: drop the pad
+                true_len = None
+            logits, ms = self._dispatch("prefill", self._prefill_fn, params,
+                                        tokens, true_len, encoder_frames)
+            first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return Prefix(state=ms, first_token=first, logits=logits,
                       length=tl, true_length=tl)
 
-    def _prefill_chunked(self, params, tokens, tl: int) -> Prefix:
+    def _prefill_chunked(self, params, tokens, tl: int, sp: span) -> Prefix:
         """Host loop over the ONE compiled chunk program: pad the prompt to
         a chunk multiple, append chunk by chunk at growing offsets, keep the
         logits of the chunk holding position true_length-1 (chunks past it
@@ -1032,6 +1095,8 @@ class SOIEngine(Engine):
         loop starts at chunk R/C — prefill cost drops from O(prompt) to
         O(suffix). The final chunk (holding position true_length-1) always
         runs, so the returned logits/first token never come from the cache.
+        ``sp`` is the caller's ``engine.prefill`` span (args ``chunks`` and
+        ``hit`` are set here).
         """
         c = self._chunk
         n = (tl - 1) // c + 1
@@ -1040,51 +1105,59 @@ class SOIEngine(Engine):
             tokens = jnp.pad(tokens, ((0, 0), (0, pad)))
         elif pad < 0:
             tokens = tokens[:, :n * c]   # trailing all-pad chunks: no-ops
-        ms = self._fresh_prefix_fn(params)
+        ms = self._dispatch("fresh_prefix", self._fresh_prefix_fn, params)
         i0 = 0
         meta = None
         soi = self.cfg.soi is not None
         if self._prefix_cache:
-            toks_np = np.asarray(tokens[0][:tl])
-            block_keys = chain_keys(toks_np, self._spec.page_size)
-            meta = {"hit": 0, "hit_key": None, "tokens": toks_np,
-                    "keys": {b: k for b, k in block_keys.items()
-                             if b % self._pc_align == 0},
-                    "snapshots": {}}
-            hit = self._lookup_prefix(toks_np, tl, block_keys)
+            with span("engine.prefix_lookup"):
+                toks_np = np.asarray(tokens[0][:tl])
+                block_keys = chain_keys(toks_np, self._spec.page_size)
+                meta = {"hit": 0, "hit_key": None, "tokens": toks_np,
+                        "keys": {b: k for b, k in block_keys.items()
+                                 if b % self._pc_align == 0},
+                        "snapshots": {}}
+                hit = self._lookup_prefix(toks_np, tl, block_keys)
             if hit is not None:
                 R, key, e = hit
-                rows = {"outer": self._pad_row(self._pt_outer,
-                                               e.outer_pages)}
-                if self._pt_mid is not None:
-                    rows["mid"] = self._pad_row(self._pt_mid, e.mid_pages)
-                n_frames = R // self.cfg.soi.stride if soi else 0
-                ms = self._hydrate_fn(ms, self._live["model"], rows,
-                                      jnp.asarray(R, jnp.int32),
-                                      jnp.asarray(n_frames, jnp.int32))
-                if soi:
-                    ms = dict(ms)
-                    ms["conv_buf"] = jnp.asarray(e.conv_buf)
-                    ms["queue"] = jnp.asarray(e.queue)
+                with span("engine.hydrate"):
+                    rows = {"outer": self._pad_row(self._pt_outer,
+                                                   e.outer_pages)}
+                    if self._pt_mid is not None:
+                        rows["mid"] = self._pad_row(self._pt_mid,
+                                                    e.mid_pages)
+                    n_frames = R // self.cfg.soi.stride if soi else 0
+                    ms = self._dispatch(
+                        "hydrate", self._hydrate_fn, ms, self._live["model"],
+                        rows, jnp.asarray(R, jnp.int32),
+                        jnp.asarray(n_frames, jnp.int32))
+                    if soi:
+                        ms = dict(ms)
+                        ms["conv_buf"] = jnp.asarray(e.conv_buf)
+                        ms["queue"] = jnp.asarray(e.queue)
                 i0 = R // c
                 meta["hit"], meta["hit_key"] = R, key
                 self._pc_stats["hits"] += 1
                 self._pc_stats["tokens_skipped"] += R
             else:
                 self._pc_stats["misses"] += 1
+        sp.set(chunks=n - i0, hit=i0 * c)
         tl_dev = jnp.asarray(tl, jnp.int32)
         logits = None
         for i in range(i0, n):
-            logits, ms = self._prefill_chunk_fn(
-                params, ms, tokens[:, i * c:(i + 1) * c],
-                jnp.asarray(i * c, jnp.int32), tl_dev)
+            with span("engine.prefill_chunk", index=i):
+                logits, ms = self._dispatch(
+                    "prefill_chunk", self._prefill_chunk_fn, params, ms,
+                    tokens[:, i * c:(i + 1) * c],
+                    jnp.asarray(i * c, jnp.int32), tl_dev)
             b = (i + 1) * c
             if (meta is not None and soi and b in meta["keys"]
                     and meta["keys"][b] not in self._prefix_index):
                 # host snapshot of the SOI carries at this boundary: what a
                 # resumed prefill needs beyond the paged caches
-                meta["snapshots"][b] = (np.asarray(ms["conv_buf"]),
-                                        np.asarray(ms["queue"]))
+                with span("engine.snapshot"):
+                    meta["snapshots"][b] = (np.asarray(ms["conv_buf"]),
+                                            np.asarray(ms["queue"]))
         first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return Prefix(state=ms, first_token=first, logits=logits,
                       length=tl, true_length=tl, cache_meta=meta)
@@ -1113,9 +1186,15 @@ class SOIEngine(Engine):
                              "with speculate=K")
         self._spec_slots[s_i] = (self._speculate is not None
                                  if speculate is None else bool(speculate))
+        with span("engine.insert", slot=s_i):
+            return self._insert(prefix, decode_state, s_i)
+
+    def _insert(self, prefix: Prefix, decode_state, s_i: int):
         if not self._paged:
-            ds = self._ins(decode_state, prefix.state, prefix.first_token,
-                           jnp.asarray(slot, jnp.int32), None)
+            ds = self._dispatch("ins", self._ins, decode_state, prefix.state,
+                                prefix.first_token,
+                                jnp.asarray(s_i, jnp.int32), None)
+            self._clock[s_i] = prefix.true_length
             self._occupied[s_i] = True
             self._live = ds
             return ds
@@ -1170,9 +1249,10 @@ class SOIEngine(Engine):
                     _, write = self._pt_mid.alloc_slot(s_i, frames,
                                                        shared=shared_mid)
                     page_rows["mid"] = jnp.asarray(write)
-                new_ds = self._ins(decode_state, prefix.state,
-                                   prefix.first_token,
-                                   jnp.asarray(slot, jnp.int32), page_rows)
+                new_ds = self._dispatch(
+                    "ins", self._ins, decode_state, prefix.state,
+                    prefix.first_token, jnp.asarray(s_i, jnp.int32),
+                    page_rows)
             except Exception:
                 # transactional: a failed insert (pool exhausted mid-way,
                 # mismatched prefix state) must not leak pages into an
@@ -1214,7 +1294,8 @@ class SOIEngine(Engine):
         rows = {"outer": self._pad_row(self._pt_outer, freed_o)}
         if self._pt_mid is not None:
             rows["mid"] = self._pad_row(self._pt_mid, freed_m)
-        decode_state = self._scrub_fn(decode_state, rows)
+        decode_state = self._dispatch("scrub", self._scrub_fn, decode_state,
+                                      rows)
         self._live = decode_state
         return decode_state
 
@@ -1248,30 +1329,52 @@ class SOIEngine(Engine):
     def generate(self, params, decode_state):
         if self._speculate is not None:
             return self._generate_spec(params, decode_state)
-        if self._paged:
-            # back the cache row each live slot writes this step —
-            # grow-by-one allocation plus COW off shared prefix pages —
-            # then hand the updated maps to the compiled step as data
-            st = self.cfg.soi.stride if self.cfg.soi is not None else 0
-            for slot in np.nonzero(self._occupied)[0]:
-                t = int(self._clock[slot])
-                if self._pt_outer is not None:
-                    decode_state, _ = self._back_write_page(
-                        decode_state, self._pt_outer, slot, t, "outer")
-                if self._pt_mid is not None and t % st == 0:
-                    decode_state, _ = self._back_write_page(
-                        decode_state, self._pt_mid, slot, t // st, "mid")
-            decode_state = self._flush_cow(decode_state)
-            decode_state = dict(decode_state)
-            decode_state["model"] = self._refresh_page_maps(
-                decode_state["model"])
-        # the host mirror of every slot's decode clock advances for paged
-        # AND dense engines: phase-aligned admission (phase_gap) reads it,
-        # not just the paged backing loop above
-        self._clock[self._occupied] += 1
-        new_ds, data, logits, met = self._gen(params, decode_state)
+        with self._step_span() as sp:
+            if self._paged:
+                # back the cache row each live slot writes this step —
+                # grow-by-one allocation plus COW off shared prefix pages —
+                # then hand the updated maps to the compiled step as data
+                cow0 = self._pc_stats["cow_copies"]
+                with span("engine.back_pages"):
+                    decode_state, pages = self._back_step(decode_state)
+                sp.set(pages=pages,
+                       cow=self._pc_stats["cow_copies"] - cow0)
+                decode_state = self._upload_backing(decode_state)
+            # the host mirror of every slot's decode clock advances for
+            # paged AND dense engines: phase-aligned admission (phase_gap)
+            # reads it, not just the paged backing loop above
+            self._clock[self._occupied] += 1
+            new_ds, data, logits, met = self._dispatch("gen", self._gen,
+                                                       params, decode_state)
         self._live = new_ds
         return new_ds, ResultTokens(data=data, logits=logits, metrics=met)
+
+    def _back_step(self, decode_state):
+        """Back the position every occupied slot writes this step (and its
+        middle frame on phase 0); returns the state and the pages newly
+        allocated."""
+        st = self.cfg.soi.stride if self.cfg.soi is not None else 0
+        pages = 0
+        for slot in np.nonzero(self._occupied)[0]:
+            t = int(self._clock[slot])
+            if self._pt_outer is not None:
+                decode_state, fresh = self._back_write_page(
+                    decode_state, self._pt_outer, slot, t, "outer")
+                pages += fresh is not None
+            if self._pt_mid is not None and t % st == 0:
+                decode_state, fresh = self._back_write_page(
+                    decode_state, self._pt_mid, slot, t // st, "mid")
+                pages += fresh is not None
+        return decode_state, pages
+
+    def _upload_backing(self, decode_state):
+        """Land the step's COW copies and hand the changed page maps to
+        the compiled step."""
+        with span("engine.flush_cow"):
+            decode_state = self._flush_cow(decode_state)
+        decode_state = dict(decode_state)
+        decode_state["model"] = self._refresh_page_maps(decode_state["model"])
+        return decode_state
 
     # -- speculative windows ---------------------------------------------
 
@@ -1342,10 +1445,16 @@ class SOIEngine(Engine):
         # generate()'s except path already dropped those
 
     def _generate_spec(self, params, decode_state):
+        with self._step_span() as sp:
+            return self._spec_window(params, decode_state, sp)
+
+    def _spec_window(self, params, decode_state, sp: span):
         k = self._speculate
         if self._paged:
+            cow0 = self._pc_stats["cow_copies"]
             try:
-                decode_state = self._back_spec_window(decode_state)
+                with span("engine.back_pages"):
+                    decode_state = self._back_spec_window(decode_state)
             except Exception:
                 # transactional: a failed backing (pool exhausted mid-loop)
                 # must not leak the pages already grown for this window;
@@ -1355,13 +1464,12 @@ class SOIEngine(Engine):
                     self._drop_spec_pending(slot)
                 self._live = self._flush_cow(self._live)
                 raise
-            decode_state = self._flush_cow(decode_state)
-            decode_state = dict(decode_state)
-            decode_state["model"] = self._refresh_page_maps(
-                decode_state["model"])
+            sp.set(pages=sum(map(len, self._spec_pending)),
+                   cow=self._pc_stats["cow_copies"] - cow0)
+            decode_state = self._upload_backing(decode_state)
         spec_mask = jnp.asarray(self._spec_slots)
-        new_ds, data, logits, met = self._specgen(params, decode_state,
-                                                  spec_mask)
+        new_ds, data, logits, met = self._dispatch(
+            "specgen", self._specgen, params, decode_state, spec_mask)
         # the accepted counts gate host bookkeeping (clock advance, page
         # rollback), so every window syncs the result row to the host —
         # the same single device->host copy callers make to read tokens;
@@ -1427,6 +1535,10 @@ class SOIEngine(Engine):
             raise ValueError(
                 f"free_slot({s_i}): slot is not occupied — it was never "
                 f"inserted into, or already freed (double-free)")
+        with span("engine.free_slot", slot=s_i):
+            return self._free_slot(decode_state, s_i)
+
+    def _free_slot(self, decode_state, s_i: int):
         # an aborted backing (pool exhausted mid-loop) can leave COW pairs
         # pending; land them before this release can recycle a pair's
         # destination page
@@ -1448,7 +1560,8 @@ class SOIEngine(Engine):
             rows = {"outer": sl}
             if self.cfg.soi is not None:
                 rows["mid"] = sl
-            ds = self._release_fn(decode_state, sl, rows)
+            ds = self._dispatch("release", self._release_fn, decode_state,
+                                sl, rows)
             self._live = ds
             return ds
         # released-page rows pad to the fixed pages_per_slot length (extra
@@ -1463,8 +1576,8 @@ class SOIEngine(Engine):
             rows["mid"] = self._pad_row(self._pt_mid,
                                         self._pt_mid.release(s_i))
         self._clock[s_i] = 0
-        ds = self._release_fn(decode_state, jnp.asarray(s_i, jnp.int32),
-                              rows)
+        ds = self._dispatch("release", self._release_fn, decode_state,
+                            jnp.asarray(s_i, jnp.int32), rows)
         self._live = ds
         return ds
 

@@ -14,6 +14,11 @@ the per-slot clock vector ``state["t"]: (B,)``:
     that are mid-window keep serving their cached partial states while
     their neighbours recompute — mixed-phase batches decode bit-exactly.
 
+The compiled step names its parts with ``jax.named_scope``: ``soi_pre``,
+``soi_middle`` (the cond's true branch), ``soi_post`` and ``lm_head``
+(``cast_params`` comes from the cast itself), so device time in a trace
+can be put down to them.
+
 This replaces the ``steppers[t % stride]`` caller-side dispatch of the old
 ``make_soi_steppers`` shim (removed): phase is data, not a compiled-program
 index, which is what makes slot-based continuous batching possible.
@@ -166,13 +171,15 @@ def generate_step(params, cfg: ModelCfg, state: dict, tokens, *,
     mid_pg = pages.get("mid") if pages else None
 
     x = D._embed_one(params, cfg, tokens, constrain, t=t)
-    x, new_state["pre"] = _run_segments(pre_p, pre_s, state["pre"], cfg, x, t,
-                                        constrain, pages=outer_pg)
+    with jax.named_scope("soi_pre"):
+        x, new_state["pre"] = _run_segments(pre_p, pre_s, state["pre"], cfg,
+                                            x, t, constrain, pages=outer_pg)
     skip = x
     window = jnp.concatenate([state["conv_buf"], x[:, None]], axis=1)
     xc = jnp.einsum("bkd,kde->be", window, soi_p["compress"].astype(x.dtype))
     s_pos = t // st                   # per-slot compressed position
 
+    @jax.named_scope("soi_middle")
     def middle(_):
         # Paged middle: mid-window slots must not commit, so their page rows
         # are masked to the null page — the write lands on discarded memory
@@ -208,7 +215,9 @@ def generate_step(params, cfg: ModelCfg, state: dict, tokens, *,
 
     fused = jnp.einsum("bc,cd->bd", jnp.concatenate([xu, skip], axis=-1),
                        soi_p["fuse"].astype(x.dtype))
-    x, new_state["post"] = _run_segments(post_p, post_s, state["post"], cfg,
-                                         fused, t, constrain, pages=outer_pg)
+    with jax.named_scope("soi_post"):
+        x, new_state["post"] = _run_segments(post_p, post_s, state["post"],
+                                             cfg, fused, t, constrain,
+                                             pages=outer_pg)
     new_state["t"] = t + 1 if active is None else jnp.where(active, t + 1, t)
     return D._logits_one(params, cfg, x), new_state

@@ -51,8 +51,11 @@ observability (``repro.obs``): the engine is built with ``telemetry=True``
 deferred drain — no extra host sync), every request's lifecycle is traced
 (queued → prefill → insert → first token → decode commits → done), and at
 exit the Perfetto-openable Chrome trace and/or the flat metrics JSON
-(registry snapshot + TTFT/TPOT percentiles) are written. Interval timing
-uses the shared monotonic clock ``repro.obs.now`` throughout.
+(registry snapshot + TTFT/ITL percentiles) are written. ``--trace-out``
+also records the engine's own spans (``repro.obs.record_spans``:
+``engine.generate`` and its parts, ``engine.prefill``, ...), written as
+the trace's ``engine`` track. Interval timing uses the shared monotonic
+clock ``repro.obs.now`` throughout.
 
 ``main`` returns a :class:`Served` record: the generated rows plus the
 engine, config and parameters that produced them, so a caller (the chip
@@ -62,6 +65,7 @@ smoke test) can inspect the compiled programs and re-run a request.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import importlib
 
@@ -75,7 +79,7 @@ from repro.engine import SOIEngine
 from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 from repro.obs import (EngineTelemetry, MetricsRegistry, Tracer, now,
-                       write_metrics, write_trace)
+                       record_spans, write_metrics, write_trace)
 
 
 @dataclasses.dataclass
@@ -144,11 +148,11 @@ def parse_args(argv=None) -> argparse.Namespace:
                          "slot (engine.can_insert(..., phase_align=True))")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="write a Perfetto-openable Chrome-trace JSON of "
-                         "per-request lifecycle spans; implies engine "
-                         "telemetry (repro.obs)")
+                         "per-request lifecycle spans and the engine's own "
+                         "spans; implies engine telemetry (repro.obs)")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write the flat metrics JSON (registry snapshot + "
-                         "TTFT/TPOT percentiles); implies engine telemetry")
+                         "TTFT/ITL percentiles); implies engine telemetry")
     ap.add_argument("--seed", type=int, default=0)
     return ap.parse_args(argv)
 
@@ -181,6 +185,14 @@ def make_engine(cfg: ModelCfg, args) -> SOIEngine:
 def main(argv=None) -> Served:
     use_compile_cache()
     args = parse_args(argv)
+    with (record_spans() if args.trace_out
+          else contextlib.nullcontext()) as spans:
+        return _serve(args, spans)
+
+
+def _serve(args, spans) -> Served:
+    """Serve the requests ``args`` describe; ``spans`` is the engine-span
+    recorder of ``--trace-out`` (None without it)."""
     cfg = model_config(args)
 
     rng = jax.random.PRNGKey(args.seed)
@@ -354,8 +366,13 @@ def main(argv=None) -> Served:
               f"active steps fully aligned (modal-bucket slot fraction "
               f"{coh['modal_fraction_mean']:.2f}; "
               f"--phase-align {'on' if args.phase_align else 'off'})")
+        lat = tracer.summary()
+        print(f"latency: TTFT p50 {1e3 * lat['ttft_p50_s']:.3f} ms, "
+              f"p99 {1e3 * lat['ttft_p99_s']:.3f} ms; gap between tokens "
+              f"p50 {1e3 * lat['itl_p50_s']:.3f} ms, "
+              f"p99 {1e3 * lat['itl_p99_s']:.3f} ms")
         if args.trace_out:
-            write_trace(tracer, args.trace_out)
+            write_trace(tracer, args.trace_out, spans.records)
             print(f"trace written to {args.trace_out} "
                   f"(open in ui.perfetto.dev)")
         if args.metrics_out:

@@ -319,6 +319,7 @@ def _embed_one(params, cfg: ModelCfg, token, constrain=_noc, t=None):
     return x
 
 
+@jax.named_scope("lm_head")
 def _logits_one(params, cfg: ModelCfg, x):
     h = norm_apply(cfg.segments[0].blocks[0].norm, params["final_norm"], x,
                    eps=cfg.norm_eps)
@@ -499,12 +500,14 @@ def prefill(params, cfg: ModelCfg, tokens, *, prefix_embeds=None,
     state = {"t": _prefill_clock(b, s, tl)}
 
     pre_c = []
-    for seg_p, seg in zip(pre_p, pre_s):
-        x, _, c = _segment_forward(seg_p, seg, cfg, x, positions=positions,
-                                   collect_cache=True, batch=b,
-                                   max_len=max_len, true_length=tl,
-                                   constrain=constrain)
-        pre_c.append(c)
+    with jax.named_scope("soi_pre"):
+        for seg_p, seg in zip(pre_p, pre_s):
+            x, _, c = _segment_forward(seg_p, seg, cfg, x,
+                                       positions=positions,
+                                       collect_cache=True, batch=b,
+                                       max_len=max_len, true_length=tl,
+                                       constrain=constrain)
+            pre_c.append(c)
     skip = x
     # Streaming conv window: the last stride-1 pre-trunk frames *before the
     # true length* (zero-padded for prompts shorter than the window) — what
@@ -531,12 +534,14 @@ def prefill(params, cfg: ModelCfg, tokens, *, prefix_embeds=None,
     mid_len = soi_mid_len(max_len, st)
     n_frames = None if tl is None else (tl + st - 1) // st
     mid_c = []
-    for seg_p, seg in zip(mid_p, mid_s):
-        xc, _, c = _segment_forward(seg_p, seg, cfg, xc, positions=cpos,
-                                    collect_cache=True, batch=b,
-                                    max_len=mid_len, true_length=n_frames,
-                                    constrain=constrain)
-        mid_c.append(c)
+    with jax.named_scope("soi_middle"):
+        for seg_p, seg in zip(mid_p, mid_s):
+            xc, _, c = _segment_forward(seg_p, seg, cfg, xc, positions=cpos,
+                                        collect_cache=True, batch=b,
+                                        max_len=mid_len,
+                                        true_length=n_frames,
+                                        constrain=constrain)
+            mid_c.append(c)
     # Extrapolation queue: stride copies of the last computed middle frame.
     # Any prompt of length >= 1 completes frame 0 (frame j sees tokens
     # <= j*stride, zero-padded like the streaming conv buffer at t=0); if a
@@ -555,12 +560,14 @@ def prefill(params, cfg: ModelCfg, tokens, *, prefix_embeds=None,
     xu = soi_extrapolate(soi, xc, s)
     x = soi_fuse(params["soi"], xu, skip)
     post_c = []
-    for seg_p, seg in zip(post_p, post_s):
-        x, _, c = _segment_forward(seg_p, seg, cfg, x, positions=positions,
-                                   collect_cache=True, batch=b,
-                                   max_len=max_len, true_length=tl,
-                                   constrain=constrain)
-        post_c.append(c)
+    with jax.named_scope("soi_post"):
+        for seg_p, seg in zip(post_p, post_s):
+            x, _, c = _segment_forward(seg_p, seg, cfg, x,
+                                       positions=positions,
+                                       collect_cache=True, batch=b,
+                                       max_len=max_len, true_length=tl,
+                                       constrain=constrain)
+            post_c.append(c)
     state["pre"], state["mid"], state["post"] = pre_c, mid_c, post_c
     logits = _logits_one(params, cfg, _last_real(x, tl))
     return logits, state
@@ -685,10 +692,11 @@ def prefill_chunk(params, cfg: ModelCfg, state: dict, tokens, offset,
     soi_p = params["soi"]
 
     new_pre = []
-    for seg_p, seg_c, seg in zip(pre_p, state["pre"], pre_s):
-        x, nc = _segment_chunk(seg_p, seg_c, seg, cfg, x, positions, tl,
-                               constrain=constrain)
-        new_pre.append(nc)
+    with jax.named_scope("soi_pre"):
+        for seg_p, seg_c, seg in zip(pre_p, state["pre"], pre_s):
+            x, nc = _segment_chunk(seg_p, seg_c, seg, cfg, x, positions, tl,
+                                   constrain=constrain)
+            new_pre.append(nc)
     new_state["pre"] = new_pre
     skip = x
 
@@ -705,10 +713,11 @@ def prefill_chunk(params, cfg: ModelCfg, state: dict, tokens, offset,
     fpos = j0 + jnp.arange(n_cf, dtype=jnp.int32)
     n_true = (tl + st - 1) // st      # frames the TRUE prompt completes
     new_mid = []
-    for seg_p, seg_c, seg in zip(mid_p, state["mid"], mid_s):
-        xm, nc = _segment_chunk(seg_p, seg_c, seg, cfg, xm, fpos, n_true,
-                                constrain=constrain)
-        new_mid.append(nc)
+    with jax.named_scope("soi_middle"):
+        for seg_p, seg_c, seg in zip(mid_p, state["mid"], mid_s):
+            xm, nc = _segment_chunk(seg_p, seg_c, seg, cfg, xm, fpos,
+                                    n_true, constrain=constrain)
+            new_mid.append(nc)
     new_state["mid"] = new_mid
 
     # Conv window carry -> last st-1 pre-trunk rows BEFORE the true length.
@@ -743,10 +752,11 @@ def prefill_chunk(params, cfg: ModelCfg, state: dict, tokens, offset,
     from repro.models.transformer import soi_fuse
     x = soi_fuse(soi_p, up, skip)
     new_post = []
-    for seg_p, seg_c, seg in zip(post_p, state["post"], post_s):
-        x, nc = _segment_chunk(seg_p, seg_c, seg, cfg, x, positions, tl,
-                               constrain=constrain)
-        new_post.append(nc)
+    with jax.named_scope("soi_post"):
+        for seg_p, seg_c, seg in zip(post_p, state["post"], post_s):
+            x, nc = _segment_chunk(seg_p, seg_c, seg, cfg, x, positions, tl,
+                                   constrain=constrain)
+            new_post.append(nc)
     new_state["post"] = new_post
     li = jnp.clip(tl - 1 - offset, 0, c - 1)
     last = jax.lax.dynamic_index_in_dim(x, li, axis=1, keepdims=False)

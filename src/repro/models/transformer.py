@@ -44,11 +44,13 @@ def _dtype(cfg: ModelCfg):
 
 def cast_params(params, cfg: ModelCfg):
     """Mixed precision: f32 master params -> compute dtype for fwd/bwd.
-    jax.grad through the cast yields f32 grads for the f32 masters."""
+    jax.grad through the cast yields f32 grads for the f32 masters. The
+    casts carry the ``cast_params`` scope in the compiled program."""
     dt = _dtype(cfg)
-    return jax.tree.map(
-        lambda p: p.astype(dt) if hasattr(p, "dtype")
-        and p.dtype == jnp.float32 else p, params)
+    with jax.named_scope("cast_params"):
+        return jax.tree.map(
+            lambda p: p.astype(dt) if hasattr(p, "dtype")
+            and p.dtype == jnp.float32 else p, params)
 
 
 # ---------------------------------------------------------------------------

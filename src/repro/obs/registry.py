@@ -262,15 +262,14 @@ class EngineTelemetry:
 
     def snapshot_engine(self, engine) -> None:
         """Re-register the engine's scattered host-side stats as gauges:
-        compile counters, prefix-cache counters, speculative accept stats,
-        page-pool residency, and the sanctioned-drain call count. Reads
-        only host ints the engine already tracks — safe at any point of
-        the serving loop."""
+        the traces of each jitted program (``engine.compiles``),
+        prefix-cache counters, speculative accept stats, page-pool
+        residency, and the sanctioned-drain call count. Reads only host
+        ints the engine already tracks — safe at any point of the serving
+        loop."""
         reg = self.registry
-        for attr in ("prefill_compiles", "spec_compiles", "hydrate_compiles"):
-            v = getattr(engine, attr, None)
-            if v is not None:
-                reg.gauge(f"engine.{attr}").set(v)
+        for program, n in getattr(engine, "compiles", {}).items():
+            reg.gauge(f"engine.compiles.{program}").set(n)
         pc = getattr(engine, "prefix_cache_stats", None)
         if isinstance(pc, dict):
             for k, v in pc.items():

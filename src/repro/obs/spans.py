@@ -1,4 +1,16 @@
-"""Request-span tracing: the lifecycle of one request, timestamped.
+"""Spans: the engine's host work, and the lifecycle of one request.
+
+**Engine spans.** :class:`span` marks one stretch of the engine's host
+work (``engine.generate`` and its parts, ``engine.prefill``, ...). Each
+span is a ``jax.profiler.TraceAnnotation`` of the same name, so in a
+profiled run it lands in the ``.xplane.pb`` on the clock of the device
+planes, with its args as the event's stats. Inside :func:`record_spans`
+every span is also kept in memory as a :class:`SpanRecord` (name, start,
+end, parent, args) on ``repro.obs.now``. With no recorder and no
+profiler a span costs the annotation object alone. The span names, their
+nesting and args are listed in ``docs/OBSERVABILITY.md``.
+
+**Request spans.**
 
 A request moves ``queued → prefill (cache hit or cold) → insert →
 first token → per-window decode commits → done``; :class:`RequestTrace`
@@ -16,16 +28,114 @@ records each transition with the shared monotonic clock
 :class:`Tracer` owns the request traces plus the session epoch ``t0``
 every exported timestamp is relative to, and summarizes percentiles over
 completed requests (always 0.0 on an empty/idle session — never NaN).
-``repro.obs.tracefile`` renders the same traces as Chrome-trace JSON for
-Perfetto.
+The summary's ``itl_p50_s`` / ``itl_p99_s`` are taken over every gap
+between two consecutive output tokens of a request, so a stall inside a
+request shows; ``tpot_*`` are percentiles of per-request means, which
+hide such stalls. ``repro.obs.tracefile`` renders the same traces, and
+the engine spans, as Chrome-trace JSON for Perfetto.
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
+from typing import NamedTuple
+
+import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.obs.clock import now
 from repro.obs.registry import percentile
+
+
+class SpanRecord(NamedTuple):
+    """One finished span: ``parent`` is the index of the enclosing span's
+    record in :attr:`SpanRecorder.records` (None at top level)."""
+    name: str
+    start: float        # repro.obs.now seconds
+    end: float
+    parent: int | None
+    args: dict
+
+
+class SpanRecorder:
+    """The spans finished while :func:`record_spans` was active, in the
+    order they began (a span's record is reserved when it opens, so a
+    parent precedes its children)."""
+
+    def __init__(self):
+        self._slots: list = []
+        self._open: list = []           # indices of the spans still open
+
+    @property
+    def records(self) -> list:
+        return [r for r in self._slots if r is not None]
+
+    def named(self, name: str) -> list:
+        return [r for r in self._slots if r is not None and r.name == name]
+
+    def children(self, parent: SpanRecord) -> list:
+        i = next(j for j, r in enumerate(self._slots) if r is parent)
+        return [r for r in self._slots if r is not None and r.parent == i]
+
+    def _begin(self) -> int:
+        i = len(self._slots)
+        self._slots.append(None)
+        self._open.append(i)
+        return i
+
+    def _end(self, i: int, name: str, start: float, args: dict):
+        self._open.pop()
+        parent = self._open[-1] if self._open else None
+        self._slots[i] = SpanRecord(name, start, now(), parent, args)
+
+
+_RECORDER: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_obs_span_recorder", default=None)
+
+
+@contextlib.contextmanager
+def record_spans():
+    """Keep every :class:`span` finished inside the block; yields the
+    :class:`SpanRecorder`."""
+    rec = SpanRecorder()
+    token = _RECORDER.set(rec)
+    try:
+        yield rec
+    finally:
+        _RECORDER.reset(token)
+
+
+class span:
+    """``with span("engine.generate", step=3) as sp: ...`` — one named
+    stretch of host work, with args (ints or strings). ``sp.set(k=v)``
+    adds args known only at the end. A plain class, not a generator: the
+    span sits on the engine's per-step path."""
+
+    __slots__ = ("name", "args", "_ann", "_rec", "_i", "_t0")
+
+    def __init__(self, name: str, **args):
+        self.name = name
+        self.args = args
+
+    def __enter__(self) -> "span":
+        self._ann = TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
+        rec = self._rec = _RECORDER.get()
+        if rec is not None:
+            self._i = rec._begin()
+            self._t0 = now()
+        return self
+
+    def set(self, **args) -> None:
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
+    def __exit__(self, *exc) -> None:
+        if self._rec is not None:
+            self._rec._end(self._i, self.name, self._t0, self.args)
+        self._ann.__exit__(*exc)
 
 
 @dataclasses.dataclass
@@ -99,6 +209,16 @@ class RequestTrace:
             return None
         return self.first_token - self.queued
 
+    def token_gaps(self) -> np.ndarray:
+        """Seconds between consecutive output tokens, first token on: a
+        window that commits k tokens gives one gap and k - 1 zeros, as a
+        client reading the stream sees them."""
+        if self.first_token is None:
+            return np.zeros(0)
+        t = [self.first_token] + [m.t for m in self.decode_marks
+                                  for _ in range(m.tokens)]
+        return np.diff(np.asarray(t, np.float64))
+
     @property
     def tpot_s(self) -> float | None:
         """Mean seconds per decode-produced token; None before the first
@@ -146,6 +266,7 @@ class Tracer:
         ttft = [t.ttft_s for t in trs if t.ttft_s is not None]
         tpot = [t.tpot_s for t in trs if t.tpot_s is not None]
         waits = [t.queue_wait_s for t in trs if t.queue_wait_s is not None]
+        itl = np.concatenate([t.token_gaps() for t in trs] + [np.zeros(0)])
         done = [t for t in trs if t.done is not None]
         return {
             "requests": len(trs),
@@ -157,6 +278,8 @@ class Tracer:
             "ttft_p99_s": percentile(ttft, 99),
             "tpot_p50_s": percentile(tpot, 50),
             "tpot_p99_s": percentile(tpot, 99),
+            "itl_p50_s": percentile(itl, 50),
+            "itl_p99_s": percentile(itl, 99),
             "queue_wait_p50_s": percentile(waits, 50),
             "queue_wait_p99_s": percentile(waits, 99),
         }

@@ -7,7 +7,9 @@ One track (``tid``) per request; the lifecycle phases become complete
 ("X") slices — ``queued``, ``prefill`` (with cache-hit/tokens-skipped
 args), ``decode`` — and every decode commit an instant ("i") event
 carrying its token count, so accept-rate bursts are visible on the
-timeline. Timestamps are microseconds relative to the tracer's epoch.
+timeline. The engine's own spans (``repro.obs.record_spans``), when
+given, are one more track (``engine``, tid 0), nested by time, with
+their args. Timestamps are microseconds relative to the tracer's epoch.
 
 ``write_metrics`` writes the companion flat JSON: the registry snapshot
 (``MetricsRegistry.as_dict``) merged with the tracer's percentile
@@ -24,10 +26,19 @@ def _us(t0: float, t: float) -> float:
     return (t - t0) * 1e6
 
 
-def chrome_trace(tracer) -> dict:
-    """Trace Event Format document for ``tracer``'s requests."""
+def chrome_trace(tracer, spans=()) -> dict:
+    """Trace Event Format document for ``tracer``'s requests, and the
+    engine ``spans`` (:class:`repro.obs.spans.SpanRecord`)."""
     events = []
     t0 = tracer.t0
+    if spans:
+        events.append({"ph": "M", "pid": 1, "tid": 0, "name": "thread_name",
+                       "args": {"name": "engine"}})
+    for s in spans:
+        events.append({"ph": "X", "pid": 1, "tid": 0, "name": s.name,
+                       "ts": _us(t0, s.start),
+                       "dur": max(_us(t0, s.end) - _us(t0, s.start), 0.0),
+                       "args": dict(s.args)})
     for tid, tr in enumerate(tracer.traces, start=1):
         name = f"req {tr.rid}" + ("" if tr.tenant is None
                                   else f" (tenant {tr.tenant})")
@@ -60,11 +71,11 @@ def chrome_trace(tracer) -> dict:
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
-def write_trace(tracer, path) -> None:
+def write_trace(tracer, path, spans=()) -> None:
     """Write the Perfetto-openable Chrome-trace JSON."""
     path = pathlib.Path(path)
     with open(path, "w") as fh:
-        json.dump(chrome_trace(tracer), fh, indent=1)
+        json.dump(chrome_trace(tracer, spans), fh, indent=1)
         fh.write("\n")
 
 

@@ -182,6 +182,66 @@ def test_chrome_trace_shape(tmp_path):
     assert doc["trace.completed"] == 1 and doc["x"] == 1
 
 
+def test_tracer_itl_takes_every_gap_between_tokens():
+    """A stall inside one request shows in the ITL tail, where the
+    percentiles of per-request mean TPOT average it away."""
+    tracer = Tracer(t0=0.0)
+    smooth = tracer.request(0, t_queued=0.0)
+    smooth.mark_first_token(t=1.0)
+    for k in range(1, 11):
+        smooth.mark_decode(1, t=1.0 + 0.1 * k)
+    stall = tracer.request(1, t_queued=0.0)
+    stall.mark_first_token(t=1.0)
+    stall.mark_decode(1, t=1.1)
+    stall.mark_decode(1, t=2.1)              # one 1 s stall
+    stall.mark_decode(2, t=2.2)              # a window of two tokens
+    assert np.allclose(stall.token_gaps(), [0.1, 1.0, 0.1, 0.0])
+    s = tracer.summary()
+    gaps = np.concatenate([smooth.token_gaps(), stall.token_gaps()])
+    assert s["itl_p50_s"] == pytest.approx(np.percentile(gaps, 50))
+    assert s["itl_p99_s"] == pytest.approx(np.percentile(gaps, 99))
+    assert s["itl_p99_s"] > 0.8 and s["tpot_p99_s"] < 0.31
+
+
+def test_chrome_trace_engine_track():
+    from repro.obs import SpanRecord
+    tracer = Tracer(t0=10.0)
+    spans = [SpanRecord("engine.generate", 10.5, 10.75, None,
+                        {"step": 0, "mid": 1}),
+             SpanRecord("engine.dispatch", 10.6, 10.7, 0, {"program": "gen"})]
+    doc = chrome_trace(tracer, spans)
+    meta, gen, disp = doc["traceEvents"]
+    assert meta["args"] == {"name": "engine"} and meta["tid"] == 0
+    assert (gen["name"], gen["ts"], gen["dur"]) == ("engine.generate",
+                                                    500000.0, 250000.0)
+    assert gen["args"] == {"step": 0, "mid": 1}
+    assert disp["ts"] >= gen["ts"] and disp["tid"] == 0
+    assert chrome_trace(tracer)["traceEvents"] == []
+
+
+def test_span_recorder_nests_and_keeps_late_args():
+    from repro.obs import record_spans, span
+    with span("outside"):                   # no recorder: nothing kept
+        pass
+    with record_spans() as rec:
+        with span("a", x=1):
+            with span("b") as b:
+                b.set(y=2)
+            with span("c"):
+                pass
+        with span("d"):
+            pass
+    a, b, c, d = rec.records
+    assert [r.name for r in rec.records] == ["a", "b", "c", "d"]
+    assert (a.parent, b.parent, c.parent, d.parent) == (None, 0, 0, None)
+    assert b.args == {"y": 2} and a.args == {"x": 1}
+    assert rec.children(a) == [b, c] and rec.named("d") == [d]
+    assert a.start <= b.start <= b.end <= c.start <= c.end <= a.end <= d.start
+    with span("after"):
+        pass
+    assert len(rec.records) == 4
+
+
 # ------------------------------------------------------------ loadgen
 
 def test_make_trace_reproducible_and_shaped():
@@ -224,8 +284,11 @@ def test_run_load_end_to_end_with_telemetry():
         d["engine.steps"]
     occ = res.telemetry.off_phase_rate_by_occupancy()
     assert occ and all(0.0 <= v <= 1.0 for v in occ.values())
-    # snapshot gauges landed (pool residency, drain budget)
+    # snapshot gauges landed (pool residency, drain budget, the one
+    # compile counter: each program traced once)
     assert d["engine.pages.outer.high_water"] > 0
+    assert d["engine.compiles.gen"] == d["engine.compiles.prefill_chunk"] \
+        == 1
     assert d["engine.sanctioned_drains"] > 0
     # all summary scalars are BENCH-valid (finite, flat)
     assert validate_bench(s, "test") == []
