@@ -6,6 +6,13 @@ checkpointed or sharded parameter trees. ``generate`` and ``insert`` are
 jitted once each — slot index and per-slot clocks are traced data, so no
 call ever re-specializes on a request's phase or position.
 
+The jitted programs take the weights in the config's compute dtype, with
+an SOI config's stacked layers already split into its pre / middle / post
+groups. A tree of f32 master weights is cast and split once, on its first
+call, and the copy serves every later call that passes the same leaves
+(``param_casts`` counts the trees cast); without that every step would
+cast and slice the whole tree again.
+
 Two cache layouts, selected by the ``paged`` flag:
 
 * dense rings (default): every slot owns ``max_len`` cache rows up front —
@@ -38,14 +45,16 @@ page maps enter the compiled step as data, never as trace-time constants.
 
 The host work is instrumented with ``repro.obs.span``: ``engine.generate``
 (and its parts), ``engine.prefill``, ``engine.insert``, ``engine.free_slot``
-and one ``engine.dispatch`` around every jitted call. ``compiles`` counts
-the traces of each jitted program. Both are listed in
+and one ``engine.dispatch`` around every jitted call, and
+``engine.cast_params`` around the weight cast. ``compiles`` counts the
+traces of each jitted program. Both are listed in
 ``docs/OBSERVABILITY.md``.
 """
 
 from __future__ import annotations
 
 import math
+import weakref
 
 import numpy as np
 
@@ -62,7 +71,8 @@ from repro.kernels import ops as kops
 from repro.models import attention as attn
 from repro.models import decode as D
 from repro.models.attention import PagedKV
-from repro.models.transformer import _dtype, _noc, soi_partition
+from repro.models.transformer import (_dtype, _noc, cast_params,
+                                      soi_partition, split_soi_params)
 from repro.obs.spans import span
 
 # the engine's jitted programs, as ``SOIEngine.compiles`` names them
@@ -381,6 +391,12 @@ class SOIEngine(Engine):
         # generate calls (and speculative windows) so far: the step number
         # of the engine.generate span
         self._steps = 0
+        # the one cached weight cast (_compute_params): weak references to
+        # the source tree's leaves, whose identity is the key, and the tree
+        # cast and split from them; param_casts counts the trees cast
+        self.param_casts = 0
+        self._cast_src = None
+        self._cast_out = None
         if cfg.learned_pos_len and max_len > cfg.learned_pos_len:
             # jnp.take clamps out-of-bounds rows, so decodes past the table
             # would silently reuse the LAST position embedding forever —
@@ -517,6 +533,9 @@ class SOIEngine(Engine):
             self.compiles["fresh_prefix"] += 1
             return D.init_decode_state(params, cfg, 1, max_len=max_len)
 
+        def _cast_params(params):
+            return split_soi_params(cast_params(params, cfg), cfg)
+
         def _scrub_model(m: dict, rows: dict) -> dict:
             m = dict(m)
             if cfg.soi is None:
@@ -600,6 +619,7 @@ class SOIEngine(Engine):
         self._prefill_chunk_fn = checked_jit(_prefill_chunk,
                                              donate_argnums=(1,))
         self._fresh_prefix_fn = checked_jit(_fresh_prefix_state)
+        self._cast_fn = jax.jit(_cast_params)
         self._release_fn = checked_jit(_release, donate_argnums=(0,))
         self._scrub_fn = checked_jit(_scrub_pages, donate_argnums=(0,))
         self._hydrate_fn = checked_jit(_hydrate, donate_argnums=(0,))
@@ -678,6 +698,42 @@ class SOIEngine(Engine):
         self._steps += 1
         return span("engine.generate", step=self._steps - 1, mid=int(mid),
                     active=int(occ.sum()))
+
+    def _compute_params(self, params):
+        """``params`` with its f32 leaves in the config's compute dtype
+        and an SOI config's groups split apart (``split_soi_params``): the
+        tree every jitted program takes, so none of them casts or slices.
+
+        One entry, keyed on the leaves' identity: a call with the same leaf
+        objects gets the tree cast before; another tree replaces the entry,
+        and the old copy goes before the new one is made (two copies of a
+        full-size model need not fit). The entry holds the source leaves
+        weakly, so a tree the caller drops is freed. A tree with no f32
+        leaf, or a float32 config, passes through as it is; so does a tree
+        of tracers (an outer trace), where the programs cast inside. A
+        tree of ``ShapeDtypeStruct`` maps to the structs of the cast tree,
+        with nothing run."""
+        leaves = jax.tree.leaves(params)
+        src = self._cast_src
+        if (src is not None and len(src) == len(leaves)
+                and all(r() is p for r, p in zip(src, leaves))):
+            return self._cast_out
+        dt = _dtype(self.cfg)
+        f32 = [p for p in leaves
+               if getattr(p, "dtype", None) == jnp.float32]
+        if (dt == jnp.float32 or not f32
+                or any(isinstance(p, jax.core.Tracer) for p in leaves)):
+            return params
+        if any(isinstance(p, jax.ShapeDtypeStruct) for p in leaves):
+            return jax.eval_shape(self._cast_fn, params)
+        self._cast_src = self._cast_out = None
+        nbytes = sum(p.size for p in f32) * jnp.dtype(dt).itemsize
+        with span("engine.cast_params", bytes=nbytes):
+            out = self._cast_fn(params)
+        self._cast_src = [weakref.ref(p) for p in leaves]
+        self._cast_out = out
+        self.param_casts += 1
+        return out
 
     @property
     def prefill_buckets(self):
@@ -779,6 +835,10 @@ class SOIEngine(Engine):
         return decode_state
 
     def init_decode_state(self, params):
+        # cast the weights now, before a serving call needs them; the state
+        # is built from the caller's tree, as the encoder cross-K/V's dtype
+        # follows it
+        self._compute_params(params)
         enc0 = None
         if self.cfg.encoder is not None:
             # per-slot encoder K/V buffers, zero until an insert fills them
@@ -1061,6 +1121,7 @@ class SOIEngine(Engine):
         if self._chunk is not None and encoder_frames is not None:
             raise ValueError("chunked prefill supports decoder-only "
                              "stacks (no encoder_frames)")
+        params = self._compute_params(params)
         with span("engine.prefill", tokens=tl) as sp:
             if self._chunk is not None:
                 return self._prefill_chunked(params, tokens, tl, sp)
@@ -1327,6 +1388,7 @@ class SOIEngine(Engine):
         return decode_state, None
 
     def generate(self, params, decode_state):
+        params = self._compute_params(params)
         if self._speculate is not None:
             return self._generate_spec(params, decode_state)
         with self._step_span() as sp:
@@ -1598,6 +1660,7 @@ class SOIEngine(Engine):
         measurements on counter *deltas*.
         """
         cfg = self.cfg
+        params = self._compute_params(params)
         ro_params = ("params are shared by every call on the engine and "
                      "must never be donated")
         stride = cfg.soi.stride if cfg.soi is not None else 1

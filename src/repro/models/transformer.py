@@ -330,7 +330,11 @@ def soi_partition(cfg: ModelCfg):
 
 
 def _split_segment_params(params_segments, cfg: ModelCfg):
-    """Slice stacked segment params along the layer axis at SOI boundaries."""
+    """Slice stacked segment params along the layer axis at SOI boundaries.
+    Segments already split (``split_soi_params``) pass through."""
+    if isinstance(params_segments, dict):
+        return (params_segments["pre"], params_segments["mid"],
+                params_segments["post"])
     soi = cfg.soi
     pre, mid, post = [], [], []
     idx = 0
@@ -350,6 +354,17 @@ def _split_segment_params(params_segments, cfg: ModelCfg):
                 {"pre": pre, "mid": mid, "post": post}[part].append(sl)
         idx += seg.n_layers
     return pre, mid, post
+
+
+def split_soi_params(params, cfg: ModelCfg):
+    """``params`` with its stacked segments split at the SOI boundaries,
+    as ``{"pre": [...], "mid": [...], "post": [...]}``: a program given
+    this tree reads each group's weights in place, where slicing the
+    stacked tree copies them on every call. Plain configs pass through."""
+    if cfg.soi is None:
+        return params
+    pre, mid, post = _split_segment_params(params["segments"], cfg)
+    return dict(params, segments={"pre": pre, "mid": mid, "post": post})
 
 
 def soi_compress(soi_p, soi: SOILMCfg, x):

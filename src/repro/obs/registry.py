@@ -262,7 +262,8 @@ class EngineTelemetry:
 
     def snapshot_engine(self, engine) -> None:
         """Re-register the engine's scattered host-side stats as gauges:
-        the traces of each jitted program (``engine.compiles``),
+        the traces of each jitted program (``engine.compiles``), the
+        weight trees cast to the compute dtype (``engine.param_casts``),
         prefix-cache counters, speculative accept stats, page-pool
         residency, and the sanctioned-drain call count. Reads only host
         ints the engine already tracks — safe at any point of the serving
@@ -270,6 +271,8 @@ class EngineTelemetry:
         reg = self.registry
         for program, n in getattr(engine, "compiles", {}).items():
             reg.gauge(f"engine.compiles.{program}").set(n)
+        if hasattr(engine, "param_casts"):
+            reg.gauge("engine.param_casts").set(engine.param_casts)
         pc = getattr(engine, "prefix_cache_stats", None)
         if isinstance(pc, dict):
             for k, v in pc.items():

@@ -9,7 +9,9 @@ The claims under test (docs/OBSERVABILITY.md lists the spans):
   * ``compiles`` counts one trace per program, and an ``engine.dispatch``
     that traced anew says so (``traced=1``);
   * the compiled generate program puts the weight cast under the
-    ``cast_params`` scope and the cond's true branch under ``soi_middle``.
+    ``cast_params`` scope and the cond's true branch under ``soi_middle``;
+  * served, the weights are cast once, under an ``engine.cast_params``
+    span, and the programs the engine dispatches hold no cast.
 """
 
 import dataclasses
@@ -243,3 +245,30 @@ def test_compiled_generate_scopes_the_cast_and_the_middle():
                for n in true_branch)
     for scope in ("soi_pre", "soi_post", "lm_head"):
         assert any(f"/{scope}/" in n for n in op_names), scope
+
+
+def test_served_programs_carry_no_cast():
+    """The engine casts the weights once, under an ``engine.cast_params``
+    span, and the generate and prefill_chunk programs it dispatches take
+    the cast tree: no op of theirs is under the ``cast_params`` scope."""
+    cfg = _cfg(dtype="bfloat16")
+    params = _params(cfg)
+    eng = _paged(cfg)
+    with record_spans() as rec:
+        ds, _ = _serve(eng, params, {0: [(0, np.arange(1, 20))]}, 3)
+    (cast,) = rec.named("engine.cast_params")
+    n_f32 = sum(p.size for p in jax.tree.leaves(params)
+                if p.dtype == np.float32)
+    assert cast.args == {"bytes": 2 * n_f32}
+    assert eng.param_casts == 1
+    served = eng._compute_params(params)
+    ms = jax.eval_shape(eng._fresh_prefix_fn, served)
+    tok = np.zeros((1, eng.prefill_chunk), np.int32)
+    n = np.int32(eng.prefill_chunk)
+    for fn, args in ((eng._gen, (served, ds)),
+                     (eng._prefill_chunk_fn, (served, ms, tok, np.int32(0),
+                                              n))):
+        hlo = fn.lower(*args).compile().as_text()
+        op_names = re.findall(r'op_name="([^"]*)"', hlo)
+        assert op_names
+        assert not [o for o in op_names if "/cast_params/" in o]
