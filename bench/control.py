@@ -32,14 +32,15 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     args = ap.parse_args(argv)
     bench_run.use_cache()
-    from soibench import cell_run, check, model, serve, spec
+    from soibench import cell_run, check, serve, spec
 
     cell = spec.load_cell(args.workload)
     with open(bench_run.HERE / "peaks.json") as f:
         bench_run.chip(cell.chips, json.load(f))
     mix = cell.traffic
-    sizes = model.sizes(cell.config)
-    engine = serve.make_engine(model.program_config(cell.config), mix)
+    fam = cell.family
+    sizes = fam.sizes(cell.config)
+    engine = serve.make_engine(fam.program_config(cell.config), mix)
     controls = {int(s) for s in args.control_seeds.split(",") if s}
     prog, ctl = [], []
     for seed in (int(s) for s in args.seeds.split(",")):
@@ -49,12 +50,12 @@ def main(argv=None) -> int:
         sample = check.sample(loop.finished, int(mix["sample"]), seed)
         del loop
         gc.collect()
-        found = check.compare(weights, sizes, sample,
+        found = check.compare(fam, weights, sizes, sample,
                               mix["engine"]["max_len"])
         line = {"seed": seed, "program": found}
         prog.append(found["max_gap"])
         if seed in controls:
-            line["control"] = check.control(weights, sizes, sample,
+            line["control"] = check.control(fam, weights, sizes, sample,
                                             mix["engine"]["max_len"])
             ctl.append(line["control"])
         print(json.dumps(line), flush=True)

@@ -36,12 +36,12 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=1)
     args = ap.parse_args(argv)
     bench_run.use_cache()
-    from soibench import cell_run, model, serve, spec
+    from soibench import cell_run, serve, spec
 
     cell = spec.load_pair(args.config, args.traffic)
     with open(bench_run.HERE / "peaks.json") as f:
         bench_run.chip(cell.chips, json.load(f))
-    engine = serve.make_engine(model.program_config(cell.config),
+    engine = serve.make_engine(cell.family.program_config(cell.config),
                                cell.traffic)
     for rate in (float(r) for r in args.rates.split(",")):
         mix = dict(cell.traffic, rate_hz=rate)

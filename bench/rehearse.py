@@ -38,7 +38,7 @@ def main(argv=None) -> int:
 
     jax.config.update("jax_enable_compilation_cache", False)
     cell = spec.load_cell(args.workload)
-    cfg = model.program_config(cell.config)
+    cfg = cell.family.program_config(cell.config)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])

@@ -8,7 +8,9 @@ formulas. From the program it takes only the system under test
 its compiled programs and kernels carry in a trace.
 
 A cell (``BENCHMARK.json`` ``workloads``) is one configuration file under
-``bench/configs`` under one traffic file under ``bench/traffic``; its
-correctness limit is ``bench/limits/<cell>.json`` and each per-layer metric
-is a reader ``bench/metrics/<metric>.py``. All of them are found by name.
+``bench/configs`` under one traffic file under ``bench/traffic``; the
+configuration names its model family, a module of
+``bench/soibench/families``; its correctness limit is
+``bench/limits/<cell>.json`` and each per-layer metric is a reader
+``bench/metrics/<metric>.py``. All of them are found by name.
 """

@@ -72,13 +72,14 @@ def prepare(cell, seed: int, seconds: float, log, engine=None):
     compiled once per process)."""
     import jax
     mix = cell.traffic
-    sizes = model.sizes(cell.config)
+    fam = cell.family
+    sizes = fam.sizes(cell.config)
     t0 = serve.clock()
     weights = model.make_weights(cell.config, model.seed_key(seed, 0))
     jax.block_until_ready(weights)
     t1 = serve.clock()
     if engine is None:
-        engine = serve.make_engine(model.program_config(cell.config), mix)
+        engine = serve.make_engine(fam.program_config(cell.config), mix)
     tr = traffic.Traffic(mix, sizes["vocab"], seed)
     if max(p + o for p, o in tr.sizes()) > mix["engine"]["max_len"]:
         raise ValueError("the mix sends more tokens than max_len holds")
@@ -117,12 +118,13 @@ def run_cell(cell, device, peak: dict, *, seed: int, seconds: float,
     import jax
     mix = cell.traffic
     closed = mix["loop"] == "closed"
-    sizes = model.sizes(cell.config)
+    sizes = cell.family.sizes(cell.config)
     weights, loop = prepare(cell, seed, seconds, log)
     tracer = None
     if trace:
-        from soibench import profile
-        tracer = profile.Tracer(pathlib.Path("bench_trace").resolve())
+        from soibench import families, profile
+        tracer = profile.Tracer(pathlib.Path("bench_trace").resolve(),
+                                families.kernel_costs(cell.family))
     n_compiles = compiles.n
     t_open, t_close = measure(loop, mix, seconds, tracer)
     in_window = compiles.names[n_compiles:]
@@ -155,7 +157,8 @@ def run_cell(cell, device, peak: dict, *, seed: int, seconds: float,
     sample = check.sample(loop.finished, int(mix["sample"]), seed)
     del loop, run.loop
     gc.collect()
-    found = check.compare(weights, sizes, sample, mix["engine"]["max_len"])
+    found = check.compare(cell.family, weights, sizes, sample,
+                          mix["engine"]["max_len"])
     log(f"check: {found}")
     limit = cell.limits["max_gap"]
     correct = (bool(sample) and limit is not None

@@ -2,8 +2,9 @@
 
 Once the window has closed, a sample of the requests it finished, drawn
 from the seed and always holding the one with the most served tokens, is
-replayed through the plain reference (:mod:`soibench.reference`): each
-prompt with its served tokens, in one forward pass. For every served token
+replayed through the plain reference (:mod:`soibench.reference`, with the
+layers of the configuration's family module): each prompt with its served
+tokens, in one forward pass. For every served token
 the reference gives the gap by which that token's logit lies below its own
 best logit at that position. Greedy serving that computes what the
 configuration states keeps the widest gap small; the number compared is
@@ -41,18 +42,18 @@ def _sequence(req, length: int):
     return seq, slice(tl - 1, tl - 1 + n)
 
 
-def served_gaps(weights, sizes: dict, req, length: int) -> np.ndarray:
+def served_gaps(fam, weights, sizes: dict, req, length: int) -> np.ndarray:
     """Reference gap of each token ``req`` was served (first token first)."""
     import jax.numpy as jnp
     seq, served = _sequence(req, length)
     targets = np.zeros(length, np.int32)
     targets[served] = req.out
-    g = reference.gaps(weights, reference.frozen(sizes), jnp.asarray(seq),
-                       jnp.asarray(targets))
+    g = reference.gaps(fam, weights, reference.frozen(sizes),
+                       jnp.asarray(seq), jnp.asarray(targets))
     return np.asarray(g)[served]
 
 
-def compare(weights, sizes: dict, reqs: list, max_len: int) -> dict:
+def compare(fam, weights, sizes: dict, reqs: list, max_len: int) -> dict:
     """The widest gap over every served token of ``reqs``, and where it
     lies: the prefill's first token, decode steps at phase 0 (the middle
     ran) and off-phase steps (it was extrapolated)."""
@@ -60,7 +61,7 @@ def compare(weights, sizes: dict, reqs: list, max_len: int) -> dict:
     parts = {"first": [0.0], "phase0": [0.0], "offphase": [0.0]}
     tokens = 0
     for req in reqs:
-        g = served_gaps(weights, sizes, req, length)
+        g = served_gaps(fam, weights, sizes, req, length)
         tokens += len(g)
         parts["first"].append(float(g[0]))
         # token k >= 1 came from the generate step whose input sat at
@@ -75,7 +76,7 @@ def compare(weights, sizes: dict, reqs: list, max_len: int) -> dict:
                                       for k, v in widest.items()}}
 
 
-def control(weights, sizes: dict, reqs: list, max_len: int) -> float:
+def control(fam, weights, sizes: dict, reqs: list, max_len: int) -> float:
     """The control's reading: over the same prompts and served tokens, the
     widest reference gap of the token that the float8 reference puts
     first at each served position."""
@@ -84,7 +85,7 @@ def control(weights, sizes: dict, reqs: list, max_len: int) -> float:
     widest = 0.0
     for req in reqs:
         seq, served = _sequence(req, length)
-        g = reference.control_gaps(weights, reference.frozen(sizes),
+        g = reference.control_gaps(fam, weights, reference.frozen(sizes),
                                    jnp.asarray(seq))
         widest = max(widest, float(np.max(np.asarray(g)[served])))
     return widest

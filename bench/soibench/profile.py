@@ -28,7 +28,6 @@ import re
 
 from soibench import costs
 
-KERNELS = tuple(sorted(costs.KERNELS, key=len, reverse=True))
 _SHAPE = re.compile(r"\b(pred|s8|u8|s16|u16|s32|u32|s64|u64|bf16|f16|f32|"
                     r"f64|f8e4m3fn|f8e5m2)\[([0-9,]*)\]")
 _ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -36,11 +35,13 @@ _ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
              "f32": 4, "s64": 8, "u64": 8, "f64": 8}
 
 
-def kernel_of(name: str) -> str | None:
-    """The registered kernel an HLO instruction name (``paged_decode_
-    attention.24``) belongs to; the longest match first, so
+def kernel_of(name: str, table: dict = costs.KERNELS) -> str | None:
+    """The kernel of ``table`` (name -> cost formula; the cell's is
+    :func:`soibench.families.kernel_costs`) an HLO instruction name
+    (``paged_decode_attention.24``) belongs to; the longest match, so
     ``paged_decode_attention`` is not read as a shorter one."""
-    return next((k for k in KERNELS if name.startswith(k)), None)
+    return max((k for k in table if name.startswith(k)), key=len,
+               default=None)
 
 
 def hlo_shapes(text: str):
@@ -66,13 +67,13 @@ def _as_lists(out, args) -> list:
             [[list(a.dims), a.itemsize] for a in args]]
 
 
-def parse_op(text: str) -> list:
+def parse_op(text: str, table: dict = costs.KERNELS) -> list:
     """[name, kernel, shapes, container] of one "XLA Ops" event, whose
     name is the instruction's HLO text: its instruction name, the Pallas
     kernel it calls (if any) with its operand shapes, and whether it is a
     ``while`` or ``conditional`` whose own ops are events of their own."""
     name = text.split(" = ", 1)[0].lstrip("%")
-    kernel = kernel_of(name) if "custom-call(" in text else None
+    kernel = kernel_of(name, table) if "custom-call(" in text else None
     shp = hlo_shapes(text) if kernel else None
     container = " while(" in text or " conditional(" in text
     return [name, kernel, _as_lists(*shp) if shp else None, container]
@@ -98,8 +99,9 @@ def _tpu_planes(pd):
                   key=lambda p: p.name)
 
 
-def load(path: str) -> Events:
-    """Read an ``.xplane.pb`` into :class:`Events` (first TPU only)."""
+def load(path: str, table: dict = costs.KERNELS) -> Events:
+    """Read an ``.xplane.pb`` into :class:`Events` (first TPU only), with
+    the kernels of ``table``."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
     planes = _tpu_planes(pd)
@@ -112,7 +114,7 @@ def load(path: str) -> Events:
                 programs.append([e.name, e.start_ns, e.duration_ns])
         elif line.name == "XLA Ops":
             for e in line.events:
-                name, kernel, shp, container = parse_op(e.name)
+                name, kernel, shp, container = parse_op(e.name, table)
                 ops.append([name, e.start_ns, e.duration_ns, kernel, shp,
                             container])
     for plane in pd.planes:
@@ -156,9 +158,11 @@ class Summary:
     breakdown: dict
 
 
-def reduce(ev: Events, lo: int, hi: int, in_flight, peak: dict) -> Summary:
+def reduce(ev: Events, lo: int, hi: int, in_flight, peak: dict,
+           table: dict = costs.KERNELS) -> Summary:
     """Numbers of the traced window ``[lo, hi)`` (trace ns). ``in_flight``
-    are the (start, end) ns during which some request was in flight."""
+    are the (start, end) ns during which some request was in flight;
+    ``table`` gives the cost formula of each kernel in ``ev``."""
     ops = [o for o in ev.ops if o[1] < hi and o[1] + o[2] > lo]
     busy = _clip(_union([o[1], o[1] + o[2]] for o in ops if not o[5]),
                  lo, hi)
@@ -197,8 +201,7 @@ def reduce(ev: Events, lo: int, hi: int, in_flight, peak: dict) -> Summary:
         if shp is not None and k[2] is not None:
             out = costs.Shape(tuple(shp[0][0]), shp[0][1])
             args = [costs.Shape(tuple(d), s) for d, s in shp[1]]
-            k[2] += costs.least_seconds(costs.KERNELS[kernel](out, args),
-                                        peak)
+            k[2] += costs.least_seconds(table[kernel](out, args), peak)
         else:
             k[2] = None            # a call without shapes: no roofline
 
@@ -225,8 +228,9 @@ class Tracer:
     """Profiles the window. The host spans it annotates give the offset
     between the loop's clock and the trace's."""
 
-    def __init__(self, directory):
+    def __init__(self, directory, table: dict = costs.KERNELS):
         self.dir = directory
+        self.table = table      # the cell's kernels and their costs
 
     def start(self):
         import jax
@@ -241,7 +245,7 @@ class Tracer:
                           recursive=True)
         if not paths:
             raise FileNotFoundError(f"no trace under {self.dir}")
-        return load(sorted(paths)[-1])
+        return load(sorted(paths)[-1], self.table)
 
     def summary(self, run) -> Summary:
         """The window of ``run`` (a :class:`soibench.window.Run`)."""
@@ -256,7 +260,7 @@ class Tracer:
         flight = [(ns(run.t_open + r.due),
                    ns(r.times[-1]) if r.times else hi)
                   for r in run.requests if not math.isnan(r.due)]
-        return reduce(ev, lo, hi, flight, run.peak)
+        return reduce(ev, lo, hi, flight, run.peak, self.table)
 
 
 def clock_offset(ev: Events, host_spans: list, t_open: float) -> float:

@@ -6,6 +6,8 @@ import dataclasses
 import json
 import pathlib
 
+from soibench import families
+
 ROOT = pathlib.Path(__file__).resolve().parents[2]   # the checkout
 
 
@@ -26,12 +28,28 @@ class Cell:
     per_layer: tuple      # BENCHMARK.json per_layer entries of this cell
     root: pathlib.Path = ROOT   # the checkout whose bench/ holds its files
 
+    @property
+    def family(self):
+        """The family module the configuration names
+        (:mod:`soibench.families`)."""
+        return families.of(self.config)
+
 
 def _load_json(path: pathlib.Path) -> dict:
     if not path.is_file():
         raise SpecError(f"missing file {path}")
     with open(path) as f:
         return json.load(f)
+
+
+def _config(path: pathlib.Path) -> dict:
+    """A configuration file, whose family module is in the checkout."""
+    config = _load_json(path)
+    try:
+        families.of(config)
+    except (ValueError, ModuleNotFoundError) as e:
+        raise SpecError(f"{path}: {e}") from e
+    return config
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -48,7 +66,7 @@ def load_pair(config: str, traffic: str, root: pathlib.Path = ROOT) -> Cell:
         raise SpecError(f"no config {config!r} in BENCHMARK.json")
     return Cell(name=f"{config}.{traffic}", config_name=config,
                 traffic_name=traffic, chips=1,
-                config=_load_json(root / configs[config]["file"]),
+                config=_config(root / configs[config]["file"]),
                 traffic=_load_json(root / "bench" / "traffic"
                                    / f"{traffic}.json"),
                 limits={"max_gap": None}, end_to_end=(), per_layer=(),
@@ -68,7 +86,7 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     if w["config"] not in configs:
         raise SpecError(f"workload {name} names config {w['config']!r}, "
                         f"which BENCHMARK.json does not list")
-    config = _load_json(root / configs[w["config"]]["file"])
+    config = _config(root / configs[w["config"]]["file"])
     bench_dir = root / "bench"
     traffic = _load_json(bench_dir / "traffic" / f"{w['traffic']}.json")
     limits = _load_json(bench_dir / "limits" / f"{name}.json")

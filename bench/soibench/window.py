@@ -17,7 +17,7 @@ def percentile(values, q: float):
 @dataclasses.dataclass
 class Run:
     cell: object                # soibench.spec.Cell
-    sizes: dict                 # soibench.model.sizes of the config
+    sizes: dict                 # the family's sizes of the config
     seed: int
     t_open: float               # window, on serve.clock
     t_close: float
@@ -88,13 +88,13 @@ class Run:
         """Operations the schedule requires for the work of the window:
         prompts whose prefill began in it (prefix-cache hits excluded) and
         decode tokens delivered in it."""
-        s, total = self.sizes, 0.0
+        fam, s, total = self.cell.family, self.sizes, 0.0
         for r in self.requests:
             if which in ("all", "prompt") and self.inside(r.t_prefill):
-                total += flops.prompt_flops(s, r.cached, len(r.tokens))
+                total += flops.prompt_flops(fam, s, r.cached, len(r.tokens))
             if which in ("all", "decode"):
                 tl = len(r.tokens)
                 pos = [tl + k - 1 for k, t in enumerate(r.times)
                        if k and self.inside(t)]
-                total += flops.decode_flops(s, pos)
+                total += flops.decode_flops(fam, s, pos)
         return total

@@ -3,13 +3,26 @@
 import json
 import re
 
+import pytest
 import smoke
 
-from soibench import model, spec
+from soibench import spec
 
 BENCH = json.loads((smoke.BENCH.parent / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+# a configuration never cuts a width: every key ending in _size, _dim or
+# _rank (hidden_size, moe_intermediate_size, head_dim, kv_lora_rank, ...),
+# an expansion factor or the experts per token. Counts may be the chip's
+# share: num_hidden_layers and n_routed_experts, and of the _size keys
+# vocab_size alone, by name, so that a width nobody listed stays forbidden.
+COUNTS = {"vocab_size"}
+WIDTHS = {"num_experts_per_tok", "mlp_ratio", "expand"}
+
+
+def is_width(key: str) -> bool:
+    return key in WIDTHS or (key.endswith(("_size", "_dim", "_rank"))
+                             and key not in COUNTS)
 
 
 def test_names_units_and_keys():
@@ -25,6 +38,8 @@ def test_names_units_and_keys():
         assert len(w["why"]) <= 200 and w["chips"] in (1, 4)
     for m in BENCH["per_layer"]:
         assert 0 < len(m["layer"]) <= 200
+        # each names its cells, so that a cell added later brings its own
+        assert m["workloads"], m["name"]
     assert 1 <= BENCH["run_seconds"] <= 51
     assert len(json.dumps(BENCH)) < 64 * 1024
 
@@ -33,7 +48,7 @@ def test_every_cell_has_its_files_and_its_metrics():
     e2e = {m["name"]: m for m in BENCH["end_to_end"]}
     for w in BENCH["workloads"]:
         cell = spec.load_cell(w["name"])
-        model.program_config(cell.config)
+        cell.family.program_config(cell.config)
         assert cell.limits["max_gap"] is not None
         reported = {m["name"] for m in cell.end_to_end}
         assert "setup_s" in reported and len(reported) >= 2
@@ -52,4 +67,13 @@ def test_every_config_is_used_and_keeps_its_widths():
         assert c["name"] in used
         assert c["file"].startswith("bench/")
         for key in c["reduced"]:
-            assert not key.endswith(("_dim", "_rank", "_size"))
+            assert not is_width(key), (c["name"], key)
+
+
+@pytest.mark.parametrize("key,width", [
+    ("hidden_size", True), ("shared_expert_intermediate_size", True),
+    ("head_dim", True), ("qk_rope_head_dim", True), ("kv_lora_rank", True),
+    ("num_experts_per_tok", True), ("vocab_size", False),
+    ("num_hidden_layers", False), ("n_routed_experts", False)])
+def test_widths_are_told_from_counts(key, width):
+    assert is_width(key) is width

@@ -4,7 +4,7 @@ far above the program, on the same prompts and served tokens."""
 import smoke
 import test_harness
 
-from soibench import check, model
+from soibench import check, families, model
 
 
 def test_float8_control_reads_above_the_program():
@@ -12,10 +12,10 @@ def test_float8_control_reads_above_the_program():
     seen = {}
     real = check.compare
 
-    def compare(weights, sizes, reqs, max_len):
-        found = real(weights, sizes, reqs, max_len)
+    def compare(fam, weights, sizes, reqs, max_len):
+        found = real(fam, weights, sizes, reqs, max_len)
         seen["program"] = found["max_gap"]
-        seen["control"] = check.control(weights, sizes, reqs, max_len)
+        seen["control"] = check.control(fam, weights, sizes, reqs, max_len)
         return found
 
     check.compare = compare
@@ -36,9 +36,10 @@ def test_control_of_the_reference_itself_is_nought():
     import jax.numpy as jnp
     from soibench import reference
     c = smoke.config("qwen3-1.7b-soi-pp")
-    s = model.sizes(c)
+    fam = families.of(c)
+    s = fam.sizes(c)
     w = model.make_weights(c, model.seed_key(9, 0))
     n = reference.padded_len(8, s["stride"])
     toks = jax.random.randint(jax.random.PRNGKey(4), (n,), 0, s["vocab"])
-    g = reference.control_gaps(w, reference.frozen(s), toks, quant=None)
+    g = reference.control_gaps(fam, w, reference.frozen(s), toks, quant=None)
     assert float(jnp.max(g)) == 0.0
